@@ -3,13 +3,14 @@
 Every measurement times an executable that already exists in the repo:
 
   gemm         jit'd ``jnp.dot`` (XLA, the fig-6 methodology)
-  gemm_pallas  the block-tiled Pallas GEMM (`repro.kernels.gemm`,
-               interpret mode on CPU)
+  gemm_pallas  the block-tiled Pallas GEMM (`repro.kernels.gemm`; compiled
+               on a TPU, interpret mode on CPU)
   elementwise  a jit'd saxpy (the PPE's vector/bandwidth path)
-  collective   `repro.parallel.collectives.bucketed_psum` under a forced
-               multi-device `shard_map` (subprocess when the running
-               process has a single device — the device count is fixed at
-               first JAX init)
+  collective   `repro.parallel.collectives.bucketed_psum` under a
+               multi-device `shard_map`: in-process over the chips present
+               on an accelerator (refused with too few), through a
+               forced-device CPU subprocess on a CPU host with a single
+               device (the device count is fixed at first JAX init)
   train_step / prefill
                end-to-end jit'd steps of the `repro.models` families at
                smoke size (`configs.base.reduced`)
@@ -104,7 +105,7 @@ def default_spec(suite: str = "quick", reps: int = 3) -> MeasureSpec:
     quick  GEMM-only (the CI calibrate-smoke lane and the acceptance
            sweep): seconds of wall time, enough signal to anchor compute
            throughput, memory bandwidth, and kernel overhead.
-    full   adds the Pallas GEMM (interpret mode — tiny shapes only),
+    full   adds the Pallas GEMM (tiny shapes: interpreted on CPU),
            elementwise/bandwidth probes, forced-2-device `bucketed_psum`
            collectives, and end-to-end model-family steps.
     """
@@ -215,8 +216,7 @@ def _measure_gemm_pallas(pt: MeasurePoint, spec: MeasureSpec) -> Dict:
     w = jnp.ones((k, n), dtype)
 
     def run():
-        ops.matmul(x, w, use_pallas=True, interpret=True) \
-            .block_until_ready()
+        ops.matmul(x, w, use_pallas=True).block_until_ready()
     best, mean = _time_fn(run, spec.warmup, spec.reps)
     return {"flops": 2.0 * m * n * k,
             "bytes": float((m * k + k * n + m * n) * db),
@@ -253,12 +253,12 @@ def _measure_collective(pt: MeasurePoint, spec: MeasureSpec) -> Dict:
     """`bucketed_psum` of a payload tree under multi-device shard_map.
 
     Requires >= ``devices`` JAX devices in-process; `run_points` routes
-    the whole collective group through a forced-device subprocess when the
-    parent is single-device (the XLA device count is fixed at first init).
+    the whole collective group through a forced-device subprocess when a
+    CPU parent is single-device (the XLA device count is fixed at first
+    init).
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from repro.parallel import collectives
@@ -275,7 +275,7 @@ def _measure_collective(pt: MeasurePoint, spec: MeasureSpec) -> Dict:
 
     @jax.jit
     def reduce(t):
-        return shard_map(
+        return jax.shard_map(
             lambda tt: collectives.bucketed_psum(tt, "x"),
             mesh=mesh, in_specs=(P(),), out_specs=P())(t)
 
@@ -407,15 +407,22 @@ def run_points(points: Sequence[MeasurePoint], spec: MeasureSpec,
                verbose: bool = False) -> int:
     """Measure ``points`` in order, invoking ``on_record`` per record.
 
-    Collective points are grouped into one forced-device subprocess when
-    the parent lacks devices; everything else runs in-process.
+    Collective points need ``spec.collective_devices`` devices.  When the
+    process has fewer, a CPU host measures them in one forced-device CPU
+    subprocess; an accelerator host refuses them (a CPU child would time
+    the host, not the chip) and says so.  Everything else runs in-process.
     """
     import jax
     n = 0
     need_sub = [p for p in points if p.kind == "collective"] \
         if jax.local_device_count() < spec.collective_devices else []
     sub_keys = {p.key() for p in need_sub}
-    if need_sub:
+    if need_sub and jax.default_backend() != "cpu":
+        print(f"# refused {len(need_sub)} collective points: they need "
+              f"{spec.collective_devices} {jax.default_backend()} devices "
+              f"and this process has {jax.local_device_count()}",
+              file=sys.stderr, flush=True)
+    elif need_sub:
         for rec in _collective_subprocess(spec, sorted(sub_keys)):
             if rec["key"] in sub_keys:
                 on_record(rec)

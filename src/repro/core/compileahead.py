@@ -31,8 +31,9 @@ two coordinated layers:
    `pathfinder._COMPILED`, so the device stage only dispatches warm
    functions.  Submissions are deduped fleet-wide within the process (one
    compile per (key, signature)), submitted keys are pinned against LRU
-   eviction until first dispatch, and a lookahead miss falls back to the
-   lazy inline compile (counted as `stall_seconds`).
+   eviction until first dispatch, and a lookahead miss compiles inline
+   (counted as `stall_seconds`).  A failed compile is raised by the
+   dispatch that needs it.
 
 Bucketing is on by default and is an execution-only change: chunk hashes,
 point keys, record payloads, and frontier merges are unaffected.  Set env
@@ -53,7 +54,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import core
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal, Var
 
 from repro.core import pathfinder
 
@@ -102,7 +103,7 @@ class Bucket:
     member design's values at dispatch time."""
 
     id: int
-    jaxpr: "core.Jaxpr"            # constvars=[]; invars = coeffs + data
+    jaxpr: Jaxpr                   # constvars=[]; invars = coeffs + data
     classes: Tuple[tuple, ...]     # (dtype_str, shape) per coeff pack
     class_sizes: Tuple[int, ...]
     slots: Tuple[Tuple[int, int], ...]  # per coeff invar: (class, index)
@@ -121,7 +122,7 @@ class Bucket:
 
         def scalar(packs, *data):
             coeffs = [packs[c][i] for c, i in slots]
-            out = core.eval_jaxpr(jaxpr, [], *coeffs, *data)
+            out = jax.core.eval_jaxpr(jaxpr, [], *coeffs, *data)
             return out[0] if len(out) == 1 else tuple(out)
 
         return scalar
@@ -140,21 +141,14 @@ class DesignVector:
                      for p in self.packs)
 
 
-def _shaped(aval):
-    try:
-        return core.raise_to_shaped(aval)
-    except Exception:
-        return aval
-
-
 def _aval_sig(aval) -> tuple:
-    a = _shaped(aval)
-    return (str(getattr(a, "dtype", a)), tuple(getattr(a, "shape", ())),
-            bool(getattr(a, "weak_type", False)))
+    return (str(getattr(aval, "dtype", aval)),
+            tuple(getattr(aval, "shape", ())),
+            bool(getattr(aval, "weak_type", False)))
 
 
 def _hashable(x):
-    if isinstance(x, (core.Jaxpr, core.ClosedJaxpr)):
+    if isinstance(x, (Jaxpr, ClosedJaxpr)):
         return ("jaxpr", repr(x))
     if isinstance(x, dict):
         return tuple(sorted((k, _hashable(v)) for k, v in x.items()))
@@ -169,7 +163,7 @@ def _hashable(x):
         return repr(x)
 
 
-def _canonicalize(closed: "core.ClosedJaxpr"):
+def _canonicalize(closed: ClosedJaxpr):
     """Abstract literals/constvars out of a closed jaxpr.
 
     Returns ``(jaxpr, coeff_vals, coeff_avals, fingerprint)`` where
@@ -193,7 +187,7 @@ def _canonicalize(closed: "core.ClosedJaxpr"):
     for iv in jaxpr.invars:
         vid(iv)
 
-    lit_vars: List[core.Var] = []
+    lit_vars: List[Var] = []
     lit_vals: List[np.ndarray] = []
     lit_avals: List[object] = []
     new_eqns = []
@@ -203,9 +197,9 @@ def _canonicalize(closed: "core.ClosedJaxpr"):
         fp_in = []
         changed = False
         for a in eqn.invars:
-            if isinstance(a, core.Literal):
-                aval = _shaped(a.aval)
-                var = core.Var("", aval)
+            if isinstance(a, Literal):
+                aval = a.aval
+                var = Var(aval)
                 lit_vars.append(var)
                 lit_vals.append(np.asarray(a.val))
                 lit_avals.append(aval)
@@ -220,20 +214,25 @@ def _canonicalize(closed: "core.ClosedJaxpr"):
                         tuple(fp_in), out_ids))
         new_eqns.append(eqn.replace(invars=invars) if changed else eqn)
 
-    coeff_avals = [_shaped(v.aval) for v in jaxpr.constvars] + lit_avals
+    coeff_avals = [v.aval for v in jaxpr.constvars] + lit_avals
     coeff_vals = [np.asarray(c) for c in closed.consts] + lit_vals
     fp_out = tuple(
-        ("l", _aval_sig(v.aval)) if isinstance(v, core.Literal)
+        ("l", _aval_sig(v.aval)) if isinstance(v, Literal)
         else ("v", var_ids.get(v, -1)) for v in jaxpr.outvars)
     fingerprint = (
         tuple(_aval_sig(v.aval) for v in jaxpr.constvars),
         tuple(_aval_sig(v.aval) for v in jaxpr.invars),
         tuple(fp_eqns), fp_out,
     )
-    # debug_info=None: the stored result_paths no longer match the widened
-    # invar list and Jaxpr.__init__ asserts on the mismatch.
+    # the coefficient slots widen the invar list: name them in front of
+    # the traced function's own argument names
+    n_coeff = len(jaxpr.constvars) + len(lit_vars)
+    dbg = jaxpr.debug_info
+    debug_info = dbg._replace(arg_names=tuple(
+        f"coeff[{i}]" for i in range(n_coeff))
+        + tuple(dbg.safe_arg_names(len(jaxpr.invars))))
     canonical = jaxpr.replace(
-        constvars=[], eqns=new_eqns, debug_info=None,
+        constvars=[], eqns=new_eqns, debug_info=debug_info,
         invars=list(jaxpr.constvars) + lit_vars + list(jaxpr.invars))
     return canonical, coeff_vals, coeff_avals, fingerprint
 
@@ -425,6 +424,8 @@ class CompileService:
             entry, args, key, sig = item
             try:
                 entry.compile_for(args)
+            except Exception:
+                pass  # kept on the entry; the dispatch that needs it raises
             finally:
                 with self._lock:
                     self._pending.discard((key, sig))

@@ -431,15 +431,17 @@ class CompiledEntry:
     Dispatch prefers a finished AOT executable; if a compile for the
     needed signature is in flight (AOT service), the caller blocks on it
     (counted as stall_seconds) instead of compiling a duplicate; on a
-    plain miss it compiles inline (counted as compile+stall) — the
-    graceful-fallback lazy path.  One compile per (key, signature) per
-    process: `compile_for` dedupes via per-signature events.
+    plain miss it compiles inline (counted as compile+stall).  One compile
+    per (key, signature) per process: `compile_for` dedupes via
+    per-signature events.  A failed compile is recorded and raised again
+    by every dispatch that needs it — never retried lazily.
     """
 
     def __init__(self, key: tuple, wrapper: Callable):
         self.key = key
         self.wrapper = wrapper
         self.aot: Dict[tuple, Callable] = {}
+        self.failed: Dict[tuple, BaseException] = {}
         self._inflight: Dict[tuple, threading.Event] = {}
         self._lock = threading.Lock()
 
@@ -458,12 +460,15 @@ class CompiledEntry:
 
         `args` may be concrete arrays or `jax.ShapeDtypeStruct`s.  Safe to
         call from any thread; concurrent calls for one signature collapse
-        into a single compile (the rest wait).
+        into a single compile (the rest wait).  Raises the compiler's
+        error, here and in every later call for the same signature.
         """
         sig = self.signature(args)
         with self._lock:
             if sig in self.aot:
                 return
+            if sig in self.failed:
+                raise self.failed[sig]
             ev = self._inflight.get(sig)
             if ev is None:
                 ev = self._inflight[sig] = threading.Event()
@@ -475,37 +480,26 @@ class CompiledEntry:
             ev.wait()
             if stalled:
                 _add_stall_seconds(time.perf_counter() - t0)
+            if sig in self.failed:
+                raise self.failed[sig]
             return
         t0 = time.perf_counter()
         try:
-            exe = self.wrapper.lower(*self._avals(args)).compile()
-            self.aot[sig] = exe
-            _add_compile_seconds(time.perf_counter() - t0, stalled)
-        except Exception:
-            # Graceful fallback: leave no executable; __call__ will run the
-            # lazy wrapper (which compiles on first call as before).
-            _add_compile_seconds(time.perf_counter() - t0, stalled)
+            self.aot[sig] = self.wrapper.lower(*self._avals(args)).compile()
+        except Exception as e:
+            self.failed[sig] = e
+            raise
         finally:
+            _add_compile_seconds(time.perf_counter() - t0, stalled)
             with self._lock:
                 self._inflight.pop(sig, None)
             ev.set()
 
     def __call__(self, *args):
-        sig = self.signature(args)
-        exe = self.aot.get(sig)
+        exe = self.aot.get(self.signature(args))
         if exe is None:
-            with self._lock:
-                ev = self._inflight.get(sig)
-            if ev is not None:
-                t0 = time.perf_counter()
-                ev.wait()
-                _add_stall_seconds(time.perf_counter() - t0)
-                exe = self.aot.get(sig)
-            if exe is None:
-                self.compile_for(args, stalled=True)
-                exe = self.aot.get(sig)
-        if exe is None:
-            return self.wrapper(*args)
+            self.compile_for(args, stalled=True)
+            exe = self.aot[self.signature(args)]
         return exe(*args)
 
 

@@ -27,6 +27,7 @@ import functools
 import threading
 from typing import Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -120,34 +121,10 @@ _GEMM_CACHE_MAXSIZE = 65536
 _GEMM_CACHE_LOCK = threading.Lock()
 
 
-def _resolve_tracer_type() -> tuple:
-    """The public home of the Tracer base class has moved across JAX
-    releases (``jax.core.Tracer`` is deprecated in favour of
-    ``jax.extend.core`` / internal homes, and the deprecated alias is
-    removed on recent versions).  Probe the known locations once at import
-    and fall back to an empty tuple (-> duck-typed check) if none exist."""
-    import importlib
-    for mod_name in ("jax.core", "jax.extend.core", "jax._src.core"):
-        try:
-            t = getattr(importlib.import_module(mod_name), "Tracer", None)
-        except Exception:               # deprecation shims may raise
-            continue
-        if isinstance(t, type):
-            return (t,)
-    return ()
-
-
-_TRACER_TYPES = _resolve_tracer_type()
-
-
 def is_tracer(v) -> bool:
-    """True if ``v`` is an abstract JAX tracer (robust across JAX versions;
-    used to disable host-side caching under `jax.jit` / `jax.grad`)."""
-    if _TRACER_TYPES:
-        return isinstance(v, _TRACER_TYPES)
-    # last-resort duck typing: tracers carry an abstract value but no
-    # addressable device buffer
-    return hasattr(v, "aval") and not hasattr(v, "unsafe_buffer_pointer")
+    """True if ``v`` is an abstract JAX tracer (used to disable host-side
+    caching under `jax.jit` / `jax.grad`)."""
+    return isinstance(v, jax.core.Tracer)
 
 
 def _cache_key(arch: MicroArch, m, n, k, b, dtype_bytes, cfg: PPEConfig):

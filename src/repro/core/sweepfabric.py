@@ -540,7 +540,6 @@ class FabricWorker:
                  superbatch: Optional[int] = None,
                  eval_delay_s: float = 0.0,
                  max_chunks: Optional[int] = None,
-                 compile_cache: bool = True,
                  compile_ahead: Optional[int] = None,
                  bucketing: Optional[bool] = None,
                  on_idle: Optional[Callable[[], None]] = None):
@@ -563,7 +562,6 @@ class FabricWorker:
             os.environ.get("REPRO_FABRIC_EVAL_DELAY_S", eval_delay_s))
         self.stall_s = float(os.environ.get("REPRO_FABRIC_STALL_S", 0.0))
         self.max_chunks = max_chunks
-        self.compile_cache = compile_cache
         # execution-only dispatch knobs (inherited by the process-global
         # compile-ahead service); no effect on chunk hashes or commits
         self.compile_ahead = compile_ahead
@@ -669,11 +667,8 @@ class FabricWorker:
 
     # -- main loop --------------------------------------------------------
     def run(self) -> WorkerStats:
-        from repro.core import sweeppipeline, sweeprunner
+        from repro.core import sweeppipeline
         from repro.runtime import fault
-        if self.compile_cache:
-            sweeprunner.enable_compilation_cache(
-                os.path.join(self.out_dir, "xla_cache"))
         handler = fault.PreemptionHandler(on_preempt=lambda: print(
             f"# worker {self.worker_id}: preemption notice — committing "
             f"in-flight work, then exiting", file=sys.stderr, flush=True))
@@ -909,14 +904,22 @@ class FabricCoordinator:
             cmd += ["--eval-delay", str(self.eval_delay_s)]
         return cmd
 
-    def _spawn(self) -> subprocess.Popen:
+    def _env(self) -> Dict[str, str]:
         env = dict(os.environ)
         if self.worker_env:
             env.update(self.worker_env)
-        return subprocess.Popen(self.worker_cmd(), env=env)
+        return env
+
+    def _spawn(self) -> subprocess.Popen:
+        return subprocess.Popen(self.worker_cmd(), env=self._env())
 
     def run(self) -> FabricStats:
+        from repro import devices
         from repro.core import sweeprunner
+        if self.workers > 0:
+            # one process per chip: refuse before the first spawn
+            devices.check_children_platform("sweep --workers N",
+                                            self._env())
         t0 = time.perf_counter()
         init_dir(self.spec, self.out_dir,
                  frontier_only=self.frontier_only,

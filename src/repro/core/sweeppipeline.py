@@ -668,8 +668,8 @@ class PipelineExecutor:
         """Hand the pack's compiled-fn (key, padded shape) pairs to the
         AOT compile service so the executables are warm (or at least in
         flight) by the time the device stage reaches this pack.  Runs on
-        the producer side; a miss just means the device stage falls back
-        to the lazy inline compile."""
+        the producer side; a miss just means the device stage compiles
+        inline."""
         if not self.compile_ahead:
             return
         from repro.core import compileahead

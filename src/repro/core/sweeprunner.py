@@ -497,25 +497,6 @@ def pick_backend(backend: str = "auto") -> str:
     return "pipeline"
 
 
-def enable_compilation_cache(cache_dir: str) -> bool:
-    """Point JAX's persistent compilation cache at ``cache_dir``.
-
-    Compiled XLA executables are serialized to disk and reloaded by later
-    processes, so CLI cold starts and ``--resume`` invocations skip the
-    multi-second per-skeleton compiles (trace time is not cached — only
-    the XLA compile).  The setting is process-global and sticky: if a
-    cache dir is already configured (by the user or an earlier sweep in
-    this process) it is left alone and False is returned.
-    """
-    import jax
-    if jax.config.jax_compilation_cache_dir:
-        return False
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    return True
-
-
 class SweepRunner:
     """Chunked, fanned-out, checkpointed executor for one `SweepSpec`.
 
@@ -533,7 +514,6 @@ class SweepRunner:
     def __init__(self, spec: SweepSpec, out_dir: Optional[str] = None,
                  backend: str = "auto", workers: Optional[int] = None,
                  cache=pathfinder.DEFAULT_CACHE,
-                 compile_cache: bool = False,
                  superbatch: Optional[int] = None,
                  compile_ahead: Optional[int] = None,
                  bucketing: Optional[bool] = None):
@@ -545,9 +525,6 @@ class SweepRunner:
         # (an import-time `pathfinder.prediction_cache()` default froze
         # the cache object at module load — see eval_labels)
         self.cache = pathfinder.resolve_cache(cache)
-        # opt-in persistent XLA compilation cache under out_dir (the CLI
-        # enables it): resumed / repeated sweeps skip cold compiles
-        self.compile_cache = compile_cache
         self.superbatch = superbatch
         # compile-ahead lookahead depth / cross-design bucketing (None =
         # module defaults; execution-only knobs — no effect on chunk
@@ -634,9 +611,6 @@ class SweepRunner:
                     the frontier (written to DIR/frontier.jsonl, no
                     results/checkpoint stream, incompatible with resume).
         """
-        if self.compile_cache and self.out_dir is not None:
-            enable_compilation_cache(os.path.join(self.out_dir,
-                                                  "xla_cache"))
         if frontier_only:
             return self._run_frontier(max_chunks=max_chunks,
                                       capacity=frontier_capacity,
@@ -850,6 +824,9 @@ class SweepRunner:
                     commit(futs[f], f.result())
         elif self.backend == "process":
             import multiprocessing as mp
+
+            from repro import devices
+            devices.check_children_platform("--backend process")
             ctx = mp.get_context("spawn")     # fork deadlocks under JAX
             spec_dict = spec.to_dict()
             by_index = {c.index: c for c in pending}

@@ -7,8 +7,10 @@
   mlstm.py            xLSTM mLSTM decay-linear-attention (parallel form)
   ops.py              jit'd wrappers with use_pallas/interpret switches
   ref.py              pure-jnp oracles (the allclose targets)
+  common.py           interpret-mode resolution and TPU tile alignment
 
-Validated under interpret=True on CPU; interpret=False on real TPU.
+Kernels compile on a TPU and run interpreted on the CPU (the tests); an
+explicit `interpret=` overrides the platform.
 """
 
 from repro.kernels import ops, ref

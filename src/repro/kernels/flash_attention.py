@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import SUBLANE, resolve_interpret, tile
+
 NEG_INF = -1e30
 
 
@@ -74,23 +76,20 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, window: Optional[int] = None,
                     block_q: int = 128, block_kv: int = 128,
                     scale: Optional[float] = None,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """q: (b, h, sq, d); k/v: (b, h_kv, skv, d) with h % h_kv == 0.
 
     Returns (b, h, sq, d). `window`: keys with q_pos - k_pos >= window are
-    masked (local attention); None = full context.
+    masked (local attention); None = full context. Blocks are multiples
+    of 8 rows that divide the sequence, or the whole sequence.
     """
     b, h, sq, d = q.shape
     _, h_kv, skv, _ = k.shape
     assert h % h_kv == 0, (h, h_kv)
     group = h // h_kv
     scale = scale if scale is not None else d ** -0.5
-    bq = min(block_q, sq)
-    while sq % bq:
-        bq -= 1
-    bkv = min(block_kv, skv)
-    while skv % bkv:
-        bkv -= 1
+    bq = tile(block_q, sq, SUBLANE)
+    bkv = tile(block_kv, skv, SUBLANE)
     n_kv = skv // bkv
 
     qr = q.reshape(b * h, sq, d)
@@ -115,6 +114,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qr, kr, vr)
     return out.reshape(b, h, sq, d)

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import LANE, SUBLANE, resolve_interpret, tile
+
 
 def _gemm_kernel(x_ref, w_ref, o_ref, acc_ref, *, n_k: int):
     """One (bm, bn) output tile; k is the innermost grid dim."""
@@ -43,23 +45,20 @@ def _gemm_kernel(x_ref, w_ref, o_ref, acc_ref, *, n_k: int):
 def pick_block_shape(m: int, n: int, k: int,
                      bm: int = 256, bn: int = 256, bk: int = 512,
                      ) -> Tuple[int, int, int]:
-    """Clamp requested tiles to the problem size and divisor alignment."""
-    def clamp(b: int, dim: int) -> int:
-        b = min(b, dim)
-        while dim % b:
-            b -= 1
-        return max(b, 1)
-    return clamp(bm, m), clamp(bn, n), clamp(bk, k)
+    """Clamp requested tiles to divisors the TPU lowering accepts: bm a
+    multiple of 8, bn and bk multiples of 128 (bk is the lane dim of the
+    A block), each falling back to the whole dim."""
+    return tile(bm, m, SUBLANE), tile(bn, n, LANE), tile(bk, k, LANE)
 
 
 def gemm(x: jax.Array, w: jax.Array,
          block_shape: Optional[Tuple[int, int, int]] = None,
-         out_dtype=None, interpret: bool = True) -> jax.Array:
+         out_dtype=None, interpret: Optional[bool] = None) -> jax.Array:
     """C[m, n] = A[m, k] @ B[k, n] via pl.pallas_call with VMEM BlockSpecs.
 
     `block_shape` defaults to an MXU-friendly (256, 256, 512); callers feed
     CrossFlow's `best_gemm_tiling(...)` L1 triple for the model-chosen
-    tiling. interpret=True validates on CPU; real TPU sets interpret=False.
+    tiling. `interpret` defaults to the platform (`resolve_interpret`).
     """
     m, k = x.shape
     k2, n = w.shape
@@ -78,5 +77,5 @@ def gemm(x: jax.Array, w: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, ki: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w)

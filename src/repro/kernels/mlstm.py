@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import LANE, resolve_interpret, tile
+
 NEG_INF = -1e30
 
 
@@ -72,16 +74,16 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, fcq_ref, fck_ref, li_ref, o_ref,
 def mlstm_parallel(q: jax.Array, k: jax.Array, v: jax.Array,
                    f_cum: jax.Array, log_i: jax.Array,
                    block_q: int = 128, block_kv: int = 128,
-                   interpret: bool = True) -> jax.Array:
-    """q/k/v: (b, h, s, d); f_cum/log_i: (b, h, s). Returns (b, h, s, d)."""
+                   interpret: Optional[bool] = None) -> jax.Array:
+    """q/k/v: (b, h, s, d); f_cum/log_i: (b, h, s). Returns (b, h, s, d).
+
+    The gate rows ride in blocks whose lane dim is the sequence block, so
+    blocks are multiples of 128 that divide the sequence, or all of it.
+    """
     b, h, s, d = q.shape
     scale = d ** -0.5
-    bq = min(block_q, s)
-    while s % bq:
-        bq -= 1
-    bkv = min(block_kv, s)
-    while s % bkv:
-        bkv -= 1
+    bq = tile(block_q, s, LANE)
+    bkv = tile(block_kv, s, LANE)
     n_kv = s // bkv
 
     qr = q.reshape(b * h, s, d)
@@ -109,6 +111,6 @@ def mlstm_parallel(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((bq, d), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qr, kr, vr, fc, fc, li)
     return out.reshape(b, h, s, d)

@@ -1,10 +1,9 @@
 """jit'd public wrappers for the Pallas kernels.
 
-Every op takes `use_pallas` / `interpret` switches: the model code calls
-these; on this CPU container the default path is the jnp reference (XLA) so
-the 512-device dry-run can lower, while `use_pallas=True, interpret=True`
-exercises the kernels for validation and `interpret=False` is the real-TPU
-production path. CrossFlow's tiling search feeds `block_shape`.
+Every op takes `use_pallas` / `interpret` switches. The default path is the
+jnp reference (XLA), so the 512-device dry-run can lower; `use_pallas=True`
+runs the Pallas kernel, compiled on a TPU and interpreted elsewhere unless
+`interpret` says otherwise. CrossFlow's tiling search feeds `block_shape`.
 """
 
 from __future__ import annotations
@@ -25,7 +24,8 @@ from repro.kernels.rglru import rglru_scan as rglru_pallas
                                              "interpret"))
 def matmul(x: jax.Array, w: jax.Array,
            block_shape: Optional[Tuple[int, int, int]] = None,
-           use_pallas: bool = False, interpret: bool = True) -> jax.Array:
+           use_pallas: bool = False,
+           interpret: Optional[bool] = None) -> jax.Array:
     if use_pallas:
         return gemm_pallas(x, w, block_shape=block_shape,
                            interpret=interpret)
@@ -37,7 +37,7 @@ def matmul(x: jax.Array, w: jax.Array,
                                              "block_kv"))
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,
               window: Optional[int] = None, use_pallas: bool = False,
-              interpret: bool = True, block_q: int = 128,
+              interpret: Optional[bool] = None, block_q: int = 128,
               block_kv: int = 128) -> jax.Array:
     if use_pallas:
         return flash_attention(q, k, v, causal=causal, window=window,
@@ -48,7 +48,8 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def rglru_scan(a: jax.Array, b: jax.Array, h0: jax.Array,
-               use_pallas: bool = False, interpret: bool = True) -> jax.Array:
+               use_pallas: bool = False,
+               interpret: Optional[bool] = None) -> jax.Array:
     if use_pallas:
         return rglru_pallas(a, b, h0, interpret=interpret)
     return ref.rglru_scan_ref(a, b, h0)
@@ -58,7 +59,7 @@ def rglru_scan(a: jax.Array, b: jax.Array, h0: jax.Array,
                                              "block_q", "block_kv"))
 def mlstm(q: jax.Array, k: jax.Array, v: jax.Array, f_cum: jax.Array,
           log_i: jax.Array, use_pallas: bool = False,
-          interpret: bool = True, block_q: int = 128,
+          interpret: Optional[bool] = None, block_q: int = 128,
           block_kv: int = 128) -> jax.Array:
     from repro.kernels.mlstm import mlstm_parallel
     if use_pallas:

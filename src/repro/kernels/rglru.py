@@ -17,11 +17,14 @@ intermediates; DESIGN.md hardware-adaptation notes).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.common import SUBLANE, resolve_interpret, tile
 
 
 def _rglru_kernel(a_ref, b_ref, h0_ref, o_ref, carry_ref, *, bt: int):
@@ -31,32 +34,34 @@ def _rglru_kernel(a_ref, b_ref, h0_ref, o_ref, carry_ref, *, bt: int):
     def _init():
         carry_ref[...] = h0_ref[0]
 
-    def step(i, h):
-        h = a_ref[0, i] * h + b_ref[0, i]
-        o_ref[0, i] = h.astype(o_ref.dtype)
+    def step(i, h):                        # h: (1, width)
+        row = pl.ds(i, 1)
+        h = a_ref[0, row, :] * h + b_ref[0, row, :]
+        o_ref[0, row, :] = h.astype(o_ref.dtype)
         return h
 
     carry_ref[...] = jax.lax.fori_loop(0, bt, step, carry_ref[...])
 
 
 def rglru_scan(a: jax.Array, b: jax.Array, h0: jax.Array,
-               block_t: int = 128, interpret: bool = True) -> jax.Array:
+               block_t: int = 128,
+               interpret: Optional[bool] = None) -> jax.Array:
     """h_t = a_t * h_{t-1} + b_t, h_0 given. a/b: (batch, seq, width),
-    h0: (batch, width). Returns h: (batch, seq, width)."""
+    h0: (batch, width). Returns h: (batch, seq, width). Time blocks are
+    multiples of 8 rows that divide the sequence, or all of it."""
     batch, seq, width = a.shape
-    bt = min(block_t, seq)
-    while seq % bt:
-        bt -= 1
+    bt = tile(block_t, seq, SUBLANE)
     return pl.pallas_call(
         functools.partial(_rglru_kernel, bt=bt),
         grid=(batch, seq // bt),
         in_specs=[
             pl.BlockSpec((1, bt, width), lambda bi, ti: (bi, ti, 0)),
             pl.BlockSpec((1, bt, width), lambda bi, ti: (bi, ti, 0)),
-            pl.BlockSpec((1, width), lambda bi, ti: (bi, 0)),
+            pl.BlockSpec((1, 1, width), lambda bi, ti: (bi, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, bt, width), lambda bi, ti: (bi, ti, 0)),
         out_shape=jax.ShapeDtypeStruct((batch, seq, width), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((width,), jnp.float32)],
-        interpret=interpret,
-    )(a.astype(jnp.float32), b.astype(jnp.float32), h0.astype(jnp.float32))
+        scratch_shapes=[pltpu.VMEM((1, width), jnp.float32)],
+        interpret=resolve_interpret(interpret),
+    )(a.astype(jnp.float32), b.astype(jnp.float32),
+      h0.astype(jnp.float32).reshape(batch, 1, width))

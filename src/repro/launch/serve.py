@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import devices
 from repro.configs.base import ShapeCell, get_config, reduced
 from repro.core import planner as planner_lib
 from repro.launch import mesh as mesh_lib
@@ -83,6 +84,7 @@ def main() -> None:
     ap.add_argument("--mesh", default="1x1")
     ap.add_argument("--reduced", action="store_true")
     args = ap.parse_args()
+    devices.enable_compilation_cache()
     out = serve(args.arch, args.batch, args.prompt_len, args.gen,
                 tuple(int(x) for x in args.mesh.split("x")),
                 use_reduced=args.reduced)

@@ -24,7 +24,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import optim
+from repro import devices, optim
 from repro.checkpoint import CheckpointManager
 from repro.configs.base import ArchConfig, ShapeCell, get_config, reduced
 from repro.core import planner as planner_lib
@@ -201,6 +201,7 @@ def main() -> None:
     ap.add_argument("--reduced", action="store_true",
                     help="use the smoke-scale config of the arch family")
     args = ap.parse_args()
+    devices.enable_compilation_cache()
     tc = TrainConfig(arch=args.arch, steps=args.steps,
                      global_batch=args.batch, seq_len=args.seq,
                      mesh_shape=tuple(int(x) for x in args.mesh.split("x")),

@@ -19,7 +19,6 @@ from typing import Any, Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -80,13 +79,11 @@ def gpipe(fn_stage: Callable, mesh: Mesh, stage_axis: str = "stage",
             outs = jax.lax.psum(outs * mask, stage_axis)
         return outs
 
-    pspec_params = jax.tree.map(lambda _: P(stage_axis), {"_": 0})["_"]
-
     def pipelined(params_staged, x_microbatched):
         in_specs = (jax.tree.map(lambda _: P(stage_axis), params_staged),
                     P())
-        return shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                         out_specs=P(), check_rep=False)(
+        return jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                             out_specs=P(), check_vma=False)(
             params_staged, x_microbatched)
 
     return pipelined

@@ -14,8 +14,7 @@ Subcommands:
           With --out DIR the sweep runs on the chunked, resumable engine
           (repro.core.sweeprunner; default backend = the pipelined
           executor of repro.core.sweeppipeline): results stream to
-          DIR/results.jsonl, finished chunks are checkpointed, compiled
-          XLA executables persist under DIR/xla_cache, and an
+          DIR/results.jsonl, finished chunks are checkpointed, and an
           interrupted sweep continues with ZERO re-evaluation via:
 
               PYTHONPATH=src python -m repro.pathfind sweep \
@@ -129,6 +128,8 @@ from __future__ import annotations
 import argparse
 import sys
 from typing import List, Tuple
+
+from repro import devices
 
 
 def _mesh(text: str) -> Tuple[int, ...]:
@@ -291,10 +292,6 @@ def _parser() -> argparse.ArgumentParser:
     sw.add_argument("--frontier-cap", type=int, default=None,
                     help="carried device frontier capacity (default 512; "
                          "overflow is reported, never silent)")
-    sw.add_argument("--no-compile-cache", action="store_true",
-                    help="do not persist XLA executables under "
-                         "OUT/xla_cache (enabled by default with --out "
-                         "so cold starts and resumes skip recompiles)")
     sw.add_argument("--compile-ahead", type=int, default=None,
                     metavar="N",
                     help="superbatches to pack and AOT-compile ahead of "
@@ -624,9 +621,7 @@ def _cmd_sweep_runner(args) -> int:
     if rc:
         return rc
     kwargs = dict(backend=args.backend, workers=args.workers,
-                  superbatch=args.superbatch,
-                  compile_cache=bool(args.out) and not args.no_compile_cache,
-                  **_runner_exec_kwargs(args))
+                  superbatch=args.superbatch, **_runner_exec_kwargs(args))
     if args.frontier_only:
         if args.pareto:
             print("error: --frontier-only already reduces to the "
@@ -1318,6 +1313,8 @@ def _cmd_soe(args) -> int:
 
 def main(argv=None) -> int:
     args = _parser().parse_args(argv)
+    # compiled executables persist in one fixed place (repro.devices)
+    devices.enable_compilation_cache()
     try:
         return {"sweep": _cmd_sweep, "sweep-worker": _cmd_sweep_worker,
                 "plan": _cmd_plan,
@@ -1330,7 +1327,8 @@ def main(argv=None) -> int:
               file=sys.stderr)
     except KeyError as e:
         print(f"error: unknown name: {e}", file=sys.stderr)
-    except (ValueError, AttributeError, OSError) as e:
+    except (ValueError, AttributeError, OSError,
+            devices.AcceleratorBusyError) as e:
         print(f"error: {e}", file=sys.stderr)
     return 2
 

@@ -112,6 +112,27 @@ def test_runner_resume_zero_remeasurement(tmp_path, monkeypatch):
         == [p.key() for p in microbench.enumerate_points(TINY)]
 
 
+def test_collectives_on_accelerator_refused_never_sent_to_cpu(
+        monkeypatch, capsys):
+    """One chip cannot host a 2-device collective: the points are refused
+    and named, and no forced-CPU child measures them in the chip's place."""
+    import jax
+
+    spec = MeasureSpec(suite="full", collective_bytes=(1 << 16,),
+                       collective_devices=2, reps=1)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "local_device_count", lambda: 1)
+    monkeypatch.setattr(microbench, "_collective_subprocess",
+                        lambda *a: pytest.fail("forced-CPU child started"))
+    monkeypatch.setattr(microbench, "measure_point",
+                        lambda *a: pytest.fail("collective measured"))
+    got = []
+    n = microbench.run_points(microbench.enumerate_points(spec), spec,
+                              got.append)
+    assert n == 0 and got == []
+    assert "refused 1 collective points" in capsys.readouterr().err
+
+
 # ------------------------------------------------------------------- fit
 def test_fit_recovers_synthetic_ground_truth():
     template = age.cpu_host_microarch()

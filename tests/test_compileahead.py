@@ -204,6 +204,33 @@ def test_compile_and_stall_seconds_accounting():
         pathfinder.unpin_compiled(key)
 
 
+def test_compile_failure_is_raised_not_retried_lazily():
+    """A compile the service could not finish surfaces at dispatch with the
+    compiler's own error, every time, and nothing runs uncompiled."""
+    calls = []
+
+    def build():
+        def refuse(x):
+            calls.append(1)
+            raise RuntimeError("compiler refused this program")
+        return jax.jit(refuse)
+
+    key = _ukey("refused")
+    svc = compileahead.service()
+    arg = jax.ShapeDtypeStruct((4,), jnp.float32)
+    try:
+        assert svc.warm(key, build, (arg,))
+        assert svc.drain(timeout=120.0)
+        entry = pathfinder._COMPILED[key]
+        for _ in range(2):
+            with pytest.raises(RuntimeError, match="compiler refused"):
+                entry(np.ones((4,), np.float32))
+        assert len(calls) == 1, "the failed program was traced again"
+        assert not entry.aot
+    finally:
+        pathfinder.unpin_compiled(key)
+
+
 # ------------------------------------------------------------- bucketing
 def test_sibling_designs_share_one_bucket():
     def make_scalar(c):
@@ -310,10 +337,10 @@ def test_cross_backend_bit_parity_serial_pipeline_fabric(tmp_path):
     sweepfabric.init_dir(SPEC, out)
     a = sweepfabric.FabricWorker(out, worker_id="wa", ttl_s=60.0,
                                  claim_batch=1, max_chunks=1,
-                                 compile_cache=False, bucketing=True).run()
+                                 bucketing=True).run()
     assert a.n_chunks_committed == 1
     b = sweepfabric.FabricWorker(out, worker_id="wb", ttl_s=60.0,
-                                 claim_batch=2, compile_cache=False,
+                                 claim_batch=2,
                                  bucketing=True).run()
     assert b.n_chunks_committed >= 1
     records, done = sweepfabric.merge_results(out)
@@ -422,7 +449,7 @@ def test_worker_stats_journal_reports_compile_seconds(tmp_path):
     spec = dataclasses.replace(SPEC, budget_scales=(1.0,))
     sweepfabric.init_dir(spec, out)
     sweepfabric.FabricWorker(out, worker_id="wstats", ttl_s=60.0,
-                             claim_batch=2, compile_cache=False).run()
+                             claim_batch=2).run()
     import json
     with open(os.path.join(out, "workers", "stats.wstats.json")) as fh:
         stats = json.load(fh)

@@ -1,7 +1,8 @@
 """Per-kernel allclose vs ref.py oracles: shape/dtype sweeps + hypothesis.
 
-All Pallas kernels run in interpret=True on this CPU container (the kernel
-body executes in Python); real-TPU runs flip interpret=False.
+The kernels resolve to interpret mode on the CPU (the kernel body runs in
+Python) and compile on a TPU; tests/test_tpu_compile.py compiles them for a
+described v5e chip.
 """
 
 import jax
@@ -22,6 +23,18 @@ from repro.kernels.rglru import rglru_scan
 def _rand(key, shape, dtype):
     return jax.random.normal(jax.random.PRNGKey(key), shape, jnp.float32) \
         .astype(dtype)
+
+
+def test_kernels_resolve_to_interpret_mode_on_cpu(monkeypatch):
+    """No kernel defaults to interpret mode on a TPU, and every one does on
+    the CPU; an explicit flag wins."""
+    from repro.kernels import common
+    assert jax.default_backend() == "cpu"
+    assert common.resolve_interpret(None) is True
+    assert common.resolve_interpret(False) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert common.resolve_interpret(None) is False
+    assert common.resolve_interpret(True) is True
 
 
 # ---------------------------------------------------------------------- GEMM
@@ -58,6 +71,10 @@ def test_pick_block_shape_always_divides(m, n, k, bm, bn, bk):
     tm, tn, tk = pick_block_shape(m, n, k, bm, bn, bk)
     assert m % tm == 0 and n % tn == 0 and k % tk == 0
     assert 1 <= tm <= m and 1 <= tn <= n and 1 <= tk <= k
+    # tiles the TPU lowering accepts: (8, 128) multiples or the whole dim
+    assert tm % 8 == 0 or tm == m
+    assert tn % 128 == 0 or tn == n
+    assert tk % 128 == 0 or tk == k
 
 
 # ----------------------------------------------------------- flash attention

@@ -248,17 +248,14 @@ def test_write_load_chunk_order_round_trip(tmp_path):
 def test_fabric_worker_scans_in_advisory_order(tmp_path):
     out = str(tmp_path / "fab")
     sweepfabric.init_dir(SPEC, out)
-    w = sweepfabric.FabricWorker(out, worker_id="w0",
-                                 compile_cache=False)
+    w = sweepfabric.FabricWorker(out, worker_id="w0")
     assert [c.index for c in w._scan] == [0, 1, 2, 3]    # no order.json
     sweepfabric.write_chunk_order(out, [3, 1, 2, 0], FP)
-    w = sweepfabric.FabricWorker(out, worker_id="w1",
-                                 compile_cache=False)
+    w = sweepfabric.FabricWorker(out, worker_id="w1")
     assert [c.index for c in w._scan] == [3, 1, 2, 0]
     # a stale advisory file (wrong fingerprint) falls back to index order
     sweepfabric.write_chunk_order(out, [3, 1, 2, 0], "deadbeef")
-    w = sweepfabric.FabricWorker(out, worker_id="w2",
-                                 compile_cache=False)
+    w = sweepfabric.FabricWorker(out, worker_id="w2")
     assert [c.index for c in w._scan] == [0, 1, 2, 3]
 
 

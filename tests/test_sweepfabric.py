@@ -50,6 +50,30 @@ def serial_records():
     return SweepRunner(SPEC, backend="serial", cache=None).run().records
 
 
+# ------------------------------------------------------- one process per chip
+def test_coordinator_refuses_workers_on_accelerator_host(tmp_path,
+                                                         monkeypatch):
+    """Worker processes would contend for the chip: refused with the
+    reason before any is spawned, and the CLI says so with rc 2."""
+    from repro import devices, pathfind
+    monkeypatch.setattr(devices, "children_platform", lambda env=None: "tpu")
+    monkeypatch.setattr(sweepfabric.subprocess, "Popen",
+                        lambda *a, **k: pytest.fail("worker spawned"))
+    out = str(tmp_path / "fab")
+    with pytest.raises(devices.AcceleratorBusyError, match="'tpu'"):
+        FabricCoordinator(SPEC, out, workers=2).run()
+    assert pathfind.main(["sweep", "--arch", "qwen1.5-0.5b", "--mesh", "2x2",
+                          "--workers", "2", "--out",
+                          str(tmp_path / "cli")]) == 2
+
+
+def test_children_platform_reads_cpu_pin_without_a_probe(monkeypatch):
+    from repro import devices
+    monkeypatch.setattr(devices.subprocess, "run",
+                        lambda *a, **k: pytest.fail("probe process started"))
+    assert devices.children_platform({"JAX_PLATFORMS": "cpu"}) == "cpu"
+
+
 # ------------------------------------------------------------ lease protocol
 def test_lease_claim_is_exclusive(tmp_path):
     a = LeaseManager(str(tmp_path), "a")
@@ -162,8 +186,7 @@ def test_worker_cmd_carries_fabric_knobs(tmp_path):
 def test_worker_full_mode_matches_serial(tmp_path, serial_records):
     out = str(tmp_path / "fab")
     sweepfabric.init_dir(SPEC, out)
-    stats = FabricWorker(out, ttl_s=60.0, claim_batch=2,
-                         compile_cache=False).run()
+    stats = FabricWorker(out, ttl_s=60.0, claim_batch=2).run()
     assert stats.n_chunks_committed == len(CHUNKS)
     assert stats.n_points == len(serial_records)
     assert not stats.preempted and stats.n_lost_leases == 0
@@ -182,10 +205,9 @@ def test_two_sequential_workers_split_the_sweep(tmp_path, serial_records):
     out = str(tmp_path / "fab")
     sweepfabric.init_dir(SPEC, out)
     a = FabricWorker(out, worker_id="wa", ttl_s=60.0, claim_batch=1,
-                     max_chunks=2, compile_cache=False).run()
+                     max_chunks=2).run()
     assert a.n_chunks_committed == 2
-    b = FabricWorker(out, worker_id="wb", ttl_s=60.0, claim_batch=2,
-                     compile_cache=False).run()
+    b = FabricWorker(out, worker_id="wb", ttl_s=60.0, claim_batch=2).run()
     assert b.n_chunks_committed == len(CHUNKS) - 2
     records, done = sweepfabric.merge_results(out)
     assert len(done) == len(CHUNKS)
@@ -199,10 +221,9 @@ def test_worker_frontier_mode_matches_single_host(tmp_path):
     out = str(tmp_path / "fab")
     sweepfabric.init_dir(SPEC, out, frontier_only=True)
     a = FabricWorker(out, worker_id="wa", ttl_s=60.0, claim_batch=1,
-                     max_chunks=2, compile_cache=False).run()
+                     max_chunks=2).run()
     assert a.n_chunks_committed == 2
-    b = FabricWorker(out, worker_id="wb", ttl_s=60.0, claim_batch=2,
-                     compile_cache=False).run()
+    b = FabricWorker(out, worker_id="wb", ttl_s=60.0, claim_batch=2).run()
     assert a.n_chunks_committed + b.n_chunks_committed == len(CHUNKS)
     records, n_over, done = sweepfabric.merge_frontier(out)
     assert len(done) == len(CHUNKS) and n_over == 0

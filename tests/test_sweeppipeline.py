@@ -264,9 +264,8 @@ def test_cli_frontier_only_and_cache_summary(tmp_path, capsys):
     try:
         _cli_frontier_and_summary(tmp_path, capsys, pathfind)
     finally:
-        # the CLI enables the persistent compile cache under tmp_path;
-        # leaving the global config pointed at a deleted dir would make
-        # every later compile in this process log write failures
+        # the CLI points the persistent compile cache at its fixed
+        # directory; give the rest of this process its own setting back
         jax.config.update("jax_compilation_cache_dir", prev_cc)
 
 
@@ -312,17 +311,37 @@ def _cli_frontier_and_summary(tmp_path, capsys, pathfind):
                                        "frontier.jsonl"))
 
 
-def test_compilation_cache_helper(tmp_path):
+def test_compilation_cache_helper(tmp_path, monkeypatch):
+    """One place for the persistent compile cache: the environment's
+    directory when set, else the checkout's git-ignored .jax_cache — never
+    a path made from the cwd, the pid, the time or an output dir."""
     import jax
+
+    from repro import devices
     prev = jax.config.jax_compilation_cache_dir
     try:
-        jax.config.update("jax_compilation_cache_dir", None)
-        assert sweeprunner.enable_compilation_cache(str(tmp_path / "x"))
-        assert jax.config.jax_compilation_cache_dir == str(tmp_path / "x")
-        # sticky: a second sweep's dir must not steal the configured one
-        assert not sweeprunner.enable_compilation_cache(
-            str(tmp_path / "y"))
-        assert jax.config.jax_compilation_cache_dir == str(tmp_path / "x")
+        env_dir = str(tmp_path / "env_cache")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        assert devices.enable_compilation_cache() == env_dir
+        assert jax.config.jax_compilation_cache_dir == env_dir
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        fixed = os.path.join(REPO, ".jax_cache")
+        monkeypatch.chdir(tmp_path)
+        assert devices.enable_compilation_cache() == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+        # another process, started from another directory, agrees
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_COMPILATION_CACHE_DIR"}
+        env["PYTHONPATH"] = os.path.join(REPO, "src")
+        child = subprocess.run(
+            [sys.executable, "-c", "from repro import devices; "
+             "print(devices.compilation_cache_dir())"],
+            cwd=str(tmp_path), env=env, capture_output=True, text=True,
+            check=True)
+        assert child.stdout.strip() == fixed
+        with open(os.path.join(REPO, ".gitignore")) as fh:
+            assert ".jax_cache/" in fh.read().split()
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)
 

@@ -2,6 +2,9 @@
 train step -> checkpoint -> resume -> decode) on a single device."""
 
 import os
+import shutil
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -70,3 +73,20 @@ def test_planner_prediction_recorded_for_every_runnable_cell():
             assert plan.strategy.kp == 16
             n += 1
     assert n >= 10
+
+
+@pytest.mark.parametrize("where", ["checkout", "alone"])
+def test_chip_smoke_refuses_without_a_tpu(tmp_path, where):
+    """On the CPU, or copied away from the repo, the chip smoke exits
+    non-zero before any phase and prints no ok line."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    script = os.path.join(repo, "chip_smoke.py")
+    if where == "alone":
+        script = shutil.copy(script, tmp_path / "chip_smoke.py")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("PYTHONPATH", None)
+    res = subprocess.run([sys.executable, str(script)], cwd=str(tmp_path),
+                         env=env, capture_output=True, text=True,
+                         timeout=300)
+    assert res.returncode != 0
+    assert '"ok": true' not in res.stdout
