@@ -107,6 +107,12 @@ def _row_cache_put(key, ent: tuple) -> tuple:
         return ent
 
 
+def _wait_pack(pack_q: "queue.Queue"):
+    """The dispatcher's blocking read of the next packed superbatch."""
+    with jax.profiler.TraceAnnotation("repro.pipeline.wait_pack"):
+        return pack_q.get()
+
+
 def _join_producer(producer: threading.Thread, pack_q: "queue.Queue"):
     """Join the producer, draining its bounded queue while waiting.
 
@@ -736,6 +742,25 @@ class PipelineExecutor:
         per = max(self.superbatch // max(self.spec.chunk_size, 1), 1)
         return [chunks[i:i + per] for i in range(0, len(chunks), per)]
 
+    def _pack_ahead(self, chunks: Sequence) -> _Pack:
+        """The producer stage for one superbatch: pack it and hand its
+        compiled-fn shapes to the AOT service."""
+        with jax.profiler.TraceAnnotation("repro.pipeline.pack"):
+            pack = self.pack(chunks)
+            self._prefetch(pack)
+        return pack
+
+    def _write(self, pack: _Pack, commit: Callable) -> int:
+        """The writer stage for one superbatch: block on its device
+        results, fold records and commit them in chunk order.  Returns the
+        points committed."""
+        with jax.profiler.TraceAnnotation("repro.pipeline.finalize"):
+            n = 0
+            for chunk, recs in zip(pack.chunks, self.finalize(pack)):
+                n += len(recs)
+                commit(chunk, recs)
+            return n
+
     def run(self, chunks: Sequence, commit: Callable,
             verbose: bool = False) -> int:
         """Evaluate ``chunks``, invoking ``commit(chunk, records)`` in
@@ -755,14 +780,6 @@ class PipelineExecutor:
             prev: Optional[_Pack] = None
             buf: "collections.deque" = collections.deque()
             si = 0
-
-            def flush(pack: _Pack) -> int:
-                n = 0
-                for chunk, recs in zip(pack.chunks, self.finalize(pack)):
-                    n += len(recs)
-                    commit(chunk, recs)
-                return n
-
             try:
                 while si < len(slices) or buf:
                     # pack (and AOT-submit) up to compile_ahead
@@ -770,17 +787,18 @@ class PipelineExecutor:
                     # their compiles overlap this pack's device work
                     while si < len(slices) \
                             and len(buf) <= self.compile_ahead:
-                        nxt = self.pack(slices[si])
+                        buf.append(self._pack_ahead(slices[si]))
                         si += 1
-                        self._prefetch(nxt)
-                        buf.append(nxt)
                     pack = buf.popleft()
-                    self.dispatch(pack)      # async: pack N on device ...
+                    with jax.profiler.TraceAnnotation(
+                            "repro.pipeline.dispatch"):
+                        self.dispatch(pack)  # async: pack N on device ...
                     if prev is not None:
-                        n_points += flush(prev)   # ... while N-1 commits
+                        # ... while N-1 commits
+                        n_points += self._write(prev, commit)
                     prev = pack
                 if prev is not None:
-                    n_points += flush(prev)
+                    n_points += self._write(prev, commit)
             finally:
                 self._release_all_pins()
             return n_points
@@ -798,9 +816,7 @@ class PipelineExecutor:
                 for sl in slices:
                     if errors:
                         break
-                    pack = self.pack(sl)
-                    self._prefetch(pack)
-                    buf.append(pack)
+                    buf.append(self._pack_ahead(sl))
                     while len(buf) > self.compile_ahead:
                         pack_q.put(buf.popleft())
                 while buf and not errors:
@@ -821,10 +837,7 @@ class PipelineExecutor:
                 if errors:
                     continue
                 try:
-                    for chunk, recs in zip(pack.chunks,
-                                           self.finalize(pack)):
-                        n_points[0] += len(recs)
-                        commit(chunk, recs)
+                    n_points[0] += self._write(pack, commit)
                 except BaseException as e:  # noqa: BLE001 — re-raised below
                     errors.append(e)
 
@@ -836,7 +849,7 @@ class PipelineExecutor:
         writer.start()
         try:
             while True:
-                pack = pack_q.get()
+                pack = _wait_pack(pack_q)
                 if pack is None:
                     break
                 if errors:
@@ -845,7 +858,9 @@ class PipelineExecutor:
                     # async dispatch: chunk N hits the device while N+1
                     # packs (producer) and N-1 folds/commits (writer); the
                     # bounded write queue is the in-flight backpressure
-                    self.dispatch(pack)
+                    with jax.profiler.TraceAnnotation(
+                            "repro.pipeline.dispatch"):
+                        self.dispatch(pack)
                     write_q.put(pack)
                 except BaseException as e:   # noqa: BLE001
                     errors.append(e)
@@ -908,26 +923,28 @@ class PipelineExecutor:
 
             def merge_pack(pack: _Pack, state) -> Tuple[object, int]:
                 n_merged = 0
-                for g in pack.groups.values():
-                    n = len(g.ridx)
-                    if not n:
-                        continue
-                    hw, _ = self._padded(g)
-                    idx = np.full(hw.shape[0], -1, dtype=np.int32)
-                    idx[:n] = g.gidx
-                    fn = self._compiled_frontier(g, capacity)
-                    # async dispatch: the merge runs on device while the
-                    # next pack resolves on host
-                    state = fn(jnp.asarray(hw), jnp.asarray(idx), state)
-                    self._release_pins(
-                        ("frontier", g.keys, g.skel.fold_key, capacity))
-                    n_merged += n
+                with jax.profiler.TraceAnnotation("repro.pipeline.dispatch"):
+                    for g in pack.groups.values():
+                        n = len(g.ridx)
+                        if not n:
+                            continue
+                        hw, _ = self._padded(g)
+                        idx = np.full(hw.shape[0], -1, dtype=np.int32)
+                        idx[:n] = g.gidx
+                        fn = self._compiled_frontier(g, capacity)
+                        # async dispatch: the merge runs on device while
+                        # the next pack resolves on host
+                        state = fn(jnp.asarray(hw), jnp.asarray(idx), state)
+                        self._release_pins(
+                            ("frontier", g.keys, g.skel.fold_key, capacity))
+                        n_merged += n
                 return state, n_merged
 
             def commit_pack(pack: _Pack, state):
-                if on_commit is not None:
-                    host = tuple(np.asarray(x) for x in state)
-                    on_commit([c.index for c in pack.chunks], host)
+                with jax.profiler.TraceAnnotation("repro.pipeline.finalize"):
+                    if on_commit is not None:
+                        host = tuple(np.asarray(x) for x in state)
+                        on_commit([c.index for c in pack.chunks], host)
 
             if not self.threads:
                 buf: "collections.deque" = collections.deque()
@@ -935,10 +952,8 @@ class PipelineExecutor:
                 while si < len(slices) or buf:
                     while si < len(slices) \
                             and len(buf) <= self.compile_ahead:
-                        nxt = self.pack(slices[si])
+                        buf.append(self._pack_ahead(slices[si]))
                         si += 1
-                        self._prefetch(nxt)
-                        buf.append(nxt)
                     pack = buf.popleft()
                     state, n = merge_pack(pack, state)
                     n_points += n
@@ -953,9 +968,7 @@ class PipelineExecutor:
                         for sl in slices:
                             if errors:
                                 break
-                            pack = self.pack(sl)
-                            self._prefetch(pack)
-                            buf.append(pack)
+                            buf.append(self._pack_ahead(sl))
                             while len(buf) > self.compile_ahead:
                                 pack_q.put(buf.popleft())
                         while buf and not errors:
@@ -970,7 +983,7 @@ class PipelineExecutor:
                 producer.start()
                 try:
                     while True:
-                        pack = pack_q.get()
+                        pack = _wait_pack(pack_q)
                         if pack is None:
                             break
                         if errors:

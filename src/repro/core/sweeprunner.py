@@ -44,6 +44,7 @@ from concurrent.futures import ProcessPoolExecutor, ThreadPoolExecutor, \
     as_completed
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.configs.base import ARCH_IDS, get_config
@@ -343,7 +344,8 @@ def _hardware(spec: SweepSpec, logic: str, hbm: str, net: str,
         hw = _HW_CACHE.get(key)
     if hw is None:
         tech = techlib.make_tech_config(logic, hbm, net)
-        hw = age_lib.generate(tech, spec.budgets(scale))
+        with jax.profiler.TraceAnnotation("repro.age.generate"):
+            hw = age_lib.generate(tech, spec.budgets(scale))
         if spec.profile is not None:
             from repro.calibrate import profiles as profiles_lib
             hw = profiles_lib.apply_profile(hw, spec.profile)
@@ -611,10 +613,17 @@ class SweepRunner:
                     the frontier (written to DIR/frontier.jsonl, no
                     results/checkpoint stream, incompatible with resume).
         """
-        if frontier_only:
-            return self._run_frontier(max_chunks=max_chunks,
-                                      capacity=frontier_capacity,
-                                      resume=resume)
+        with jax.profiler.TraceAnnotation("repro.runner.run"):
+            if frontier_only:
+                return self._run_frontier(max_chunks=max_chunks,
+                                          capacity=frontier_capacity,
+                                          resume=resume)
+            return self._run_records(resume, max_chunks, collect, verbose)
+
+    def _run_records(self, resume: bool, max_chunks: Optional[int],
+                     collect: bool, verbose: bool) -> RunStats:
+        """Full-record mode: every point's record is committed per chunk
+        to results.jsonl (or kept in memory without an out_dir)."""
         t0 = time.perf_counter()
         stats0 = self._stat_snapshot()
         labels = enumerate_labels(self.spec)
@@ -649,14 +658,16 @@ class SweepRunner:
 
         def commit(chunk: Chunk, records: List[Dict]):
             nonlocal n_eval_points
-            n_eval_points += len(records)
-            if journal is not None:
-                journal.commit(chunk.index, chunk.hash(self._fp), records)
-            else:
-                memory_rows.extend(records)
-            if verbose:
-                print(f"# chunk {chunk.index} done "
-                      f"({len(records)} points)", flush=True)
+            with jax.profiler.TraceAnnotation("repro.runner.commit"):
+                n_eval_points += len(records)
+                if journal is not None:
+                    journal.commit(chunk.index, chunk.hash(self._fp),
+                                   records)
+                else:
+                    memory_rows.extend(records)
+                if verbose:
+                    print(f"# chunk {chunk.index} done "
+                          f"({len(records)} points)", flush=True)
 
         try:
             self._execute(pending, commit)
@@ -689,8 +700,9 @@ class SweepRunner:
         after every committed superbatch, so a SIGKILL loses at most the
         in-flight packs and `run(resume=True)` continues from the merged
         state with zero re-evaluation (the chunked-sweep semantics)."""
-        sweepexec.save_frontier_state(path, state, done, capacity,
-                                      self._fp)
+        with jax.profiler.TraceAnnotation("repro.runner.commit"):
+            sweepexec.save_frontier_state(path, state, done, capacity,
+                                          self._fp)
 
     def _load_frontier_state(self, spec_path: str, state_path: str,
                              ckpt_path: str, chunks: List[Chunk],
