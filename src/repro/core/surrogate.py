@@ -139,9 +139,15 @@ class Featurizer:
         hw0 = scale0 + 1
         out = np.zeros((len(labels), self.dim), dtype=np.float64)
         strategies: Dict[str, Strategy] = {}
-        # AGE'd hardware is memoized per process (`sweeprunner._hardware`)
-        # but pack it once per distinct tech point here anyway
-        hw_vecs: Dict[tuple, np.ndarray] = {}
+        # the distinct tech points go through AGE in one batched call
+        # (memoized per process) and are packed once each; leaves span
+        # ~17 decades (bytes vs seconds): log10
+        hkeys = list(dict.fromkeys((lb.logic, lb.hbm, lb.net, lb.scale)
+                                   for lb in labels))
+        hw_vecs = {
+            hk: np.log10(np.abs(np.asarray(pathfinder.pack_hw(hw),
+                                           dtype=np.float64)) + 1e-30)
+            for hk, hw in zip(hkeys, sweeprunner._hardware_many(spec, hkeys))}
         for i, lb in enumerate(labels):
             row = out[i]
             base, over = decode_variant(lb.cell)
@@ -168,16 +174,8 @@ class Featurizer:
                 math.log2(st.lp), float(st.ep), float(st.sp),
                 math.log2(st.devices), 1.0 if st.kind == "CR" else 0.0)
             row[scale0] = float(lb.scale)
-            hk = (lb.logic, lb.hbm, lb.net, lb.scale)
-            hv = hw_vecs.get(hk)
-            if hv is None:
-                hw = sweeprunner._hardware(spec, lb.logic, lb.hbm, lb.net,
-                                           lb.scale)
-                # leaves span ~17 decades (bytes vs seconds): log10
-                hv = hw_vecs.setdefault(
-                    hk, np.log10(np.abs(np.asarray(
-                        pathfinder.pack_hw(hw), dtype=np.float64)) + 1e-30))
-            row[hw0:hw0 + pathfinder.HW_DIM] = hv
+            row[hw0:hw0 + pathfinder.HW_DIM] = hw_vecs[
+                (lb.logic, lb.hbm, lb.net, lb.scale)]
         return out
 
     def transform(self, spec, labels: Sequence) -> np.ndarray:

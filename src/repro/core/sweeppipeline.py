@@ -78,10 +78,10 @@ QUEUE_DEPTH = 2
 PMAP_MIN_ROWS = 1024
 
 # process-global packed-hardware rows, keyed like `sweeprunner._HW_CACHE`
-# (tech axis + budget overrides + profile digest).  `pack_hw` pulls 13
-# scalars out of JAX arrays (~30us of device syncs per point) — paying
-# that once per process instead of once per run keeps the producer's
-# per-label cost at dict-lookup speed.  LRU-capped: each entry pins a
+# (tech axis + budget overrides + profile digest).  A pack's fresh rows
+# go through AGE in one batched call (`_prime_rows`); caching the packed
+# rows per process instead of per run keeps the producer's per-label
+# cost at dict-lookup speed.  LRU-capped: each entry pins a
 # MicroArch, and a long-lived process sweeping many tech/scale/profile
 # axes must not grow it forever (same treatment as roofline._GEMM_CACHE).
 _ROW_CACHE: "collections.OrderedDict[tuple, tuple]" = \
@@ -250,26 +250,41 @@ class PipelineExecutor:
     # -- memoized resolution ---------------------------------------------
     def _hw_entry(self, lb) -> tuple:
         """(hw arch, row index, row bytes, scale string) of one label."""
-        from repro.core import sweeprunner
         hkey = (lb.logic, lb.hbm, lb.net, lb.scale)
         ent = self._hw.get(hkey)
         if ent is None:
-            gkey = hkey + (self.spec.area_mm2, self.spec.power_w,
-                           sweeprunner._profile_key(self.spec))
-            cached = _row_cache_get(gkey)
-            if cached is None:
-                hw = sweeprunner._hardware(self.spec, lb.logic, lb.hbm,
-                                           lb.net, lb.scale)
-                row = pathfinder.pack_hw(hw)
-                cached = _row_cache_put(
-                    gkey, (hw, row, row.tobytes(), f"{lb.scale:g}"))
-            hw, row, rbytes, scale_str = cached
-            ridx = len(self._rows)
-            self._rows.append(row)
-            self._rowmat = None
-            ent = (hw, ridx, rbytes, scale_str)
-            self._hw[hkey] = ent
+            self._prime_rows((lb,))
+            ent = self._hw[hkey]
         return ent
+
+    def _prime_rows(self, labels) -> None:
+        """Register the hardware rows of ``labels`` that this executor
+        lacks; those the process row cache lacks too go through AGE
+        together, in one `sweeprunner._hardware_many` call."""
+        from repro.core import sweeprunner
+        tail = (self.spec.area_mm2, self.spec.power_w,
+                sweeprunner._profile_key(self.spec))
+        fresh = []
+        for hkey in dict.fromkeys((lb.logic, lb.hbm, lb.net, lb.scale)
+                                  for lb in labels):
+            if hkey in self._hw:
+                continue
+            cached = _row_cache_get(hkey + tail)
+            if cached is None:
+                fresh.append(hkey)
+            else:
+                self._add_row(hkey, cached)
+        for hkey, hw in zip(fresh,
+                            sweeprunner._hardware_many(self.spec, fresh)):
+            row = pathfinder.pack_hw(hw)
+            self._add_row(hkey, _row_cache_put(
+                hkey + tail, (hw, row, row.tobytes(), f"{hkey[3]:g}")))
+
+    def _add_row(self, hkey: tuple, cached: tuple) -> None:
+        hw, row, rbytes, scale_str = cached
+        self._hw[hkey] = (hw, len(self._rows), rbytes, scale_str)
+        self._rows.append(row)
+        self._rowmat = None
 
     def _skeleton(self, lb) -> _DesignSkeleton:
         from repro.core import sweeprunner
@@ -380,6 +395,7 @@ class PipelineExecutor:
         """Resolve + vectorize one superbatch of chunks: memoized skeleton
         and hardware-row lookups per label, one batched cache probe, and
         miss row-indices grouped per compiled function."""
+        self._prime_rows(lb for chunk in chunks for lb in chunk.labels)
         meta: List[List] = []
         cached: Dict[tuple, np.ndarray] = {}
         groups: Dict[tuple, _Group] = {}
@@ -903,6 +919,9 @@ class PipelineExecutor:
         if not all_chunks:
             return [], 0, 0
         probe = all_chunks[0].labels[0]
+        # the probe's hardware row goes through AGE with the first pack's
+        head = self._pack_slices(chunks)[0] if len(chunks) else ()
+        self._prime_rows([probe] + [lb for c in head for lb in c.labels])
         sk0 = self._skeleton(probe)
         if sk0.fold is None:
             raise ValueError(
@@ -1023,12 +1042,16 @@ class PipelineExecutor:
         vals, payload, idx, n_over = pathfinder.frontier_unpack(
             tuple(np.asarray(x) for x in state))
         by_index = {c.index: c for c in all_chunks}
-        records: List[Dict] = []
-        sk = None
-        for i in np.argsort(idx):              # enumeration order
+        order = np.argsort(idx)                 # enumeration order
+        labels = []
+        for i in order:
             gi = int(idx[i])
             chunk = by_index[gi // self.spec.chunk_size]
-            lb = chunk.labels[gi % self.spec.chunk_size]
+            labels.append(chunk.labels[gi % self.spec.chunk_size])
+        self._prime_rows(labels)
+        records: List[Dict] = []
+        sk = None
+        for i, lb in zip(order, labels):
             sk = self._skeleton(lb)
             hw = self._hw_entry(lb)[0]
             dp = self._design_point(lb, sk, hw)

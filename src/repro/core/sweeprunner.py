@@ -338,20 +338,31 @@ def _profile_key(spec: SweepSpec) -> Optional[str]:
 
 def _hardware(spec: SweepSpec, logic: str, hbm: str, net: str,
               scale: float):
-    key = (logic, hbm, net, scale, spec.area_mm2, spec.power_w,
-           _profile_key(spec))
+    return _hardware_many(spec, [(logic, hbm, net, scale)])[0]
+
+
+def _hardware_many(spec: SweepSpec, keys: Sequence[tuple]) -> List:
+    """AGE'd hardware of each ``(logic, hbm, net, scale)`` key, in order:
+    the `_HW_CACHE` misses are resolved together, in one batched,
+    compiled AGE call (`age.generate_rows`)."""
+    tail = (spec.area_mm2, spec.power_w, _profile_key(spec))
     with _HW_LOCK:
-        hw = _HW_CACHE.get(key)
-    if hw is None:
-        tech = techlib.make_tech_config(logic, hbm, net)
-        with jax.profiler.TraceAnnotation("repro.age.generate"):
-            hw = age_lib.generate(tech, spec.budgets(scale))
-        if spec.profile is not None:
-            from repro.calibrate import profiles as profiles_lib
-            hw = profiles_lib.apply_profile(hw, spec.profile)
-        with _HW_LOCK:
-            hw = _HW_CACHE.setdefault(key, hw)
-    return hw
+        out = [_HW_CACHE.get(k + tail) for k in keys]
+    miss = list(dict.fromkeys(k for k, hw in zip(keys, out) if hw is None))
+    if not miss:
+        return out
+    with jax.profiler.TraceAnnotation("repro.age.generate"):
+        hws = age_lib.generate_rows(
+            [techlib.make_tech_config(lg, hbm, net)
+             for lg, hbm, net, _ in miss],
+            [spec.budgets(scale) for *_, scale in miss])
+    if spec.profile is not None:
+        from repro.calibrate import profiles as profiles_lib
+        hws = [profiles_lib.apply_profile(hw, spec.profile) for hw in hws]
+    with _HW_LOCK:
+        got = {k: _HW_CACHE.setdefault(k + tail, hw)
+               for k, hw in zip(miss, hws)}
+    return [hw if hw is not None else got[k] for k, hw in zip(keys, out)]
 
 
 def spec_ppe(spec: SweepSpec) -> PPEConfig:

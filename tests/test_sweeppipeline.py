@@ -8,6 +8,7 @@ and overflow semantics of `frontier_merge`), resume-identity stability
 resolution, and the cache/compile hit-miss accounting on `RunStats`.
 """
 
+import collections
 import dataclasses
 import json
 import os
@@ -254,6 +255,64 @@ def test_runstats_reports_cache_and_compile_counters(tmp_path):
     assert resumed.n_chunks_skipped == resumed.n_chunks_total
     assert resumed.n_chunks_evaluated == 0
     assert resumed.n_points_evaluated == 0
+
+
+def test_fresh_hardware_rows_resolve_in_one_age_call_per_pack(
+        tmp_path, monkeypatch):
+    """Each superbatch's fresh hardware rows go through one batched AGE
+    call (no per-scalar eager rows), a rerun on the same scales makes
+    none, and the records equal those of a sweep whose rows come from
+    eager `age.generate` + `pack_hw`, label by label."""
+    from repro.core import age, sweeppipeline
+    # scales no other test uses; 2-label superbatches, so the first two
+    # packs each bring two of the 4 fresh rows and the rest bring none
+    spec = dataclasses.replace(SPEC, budget_scales=(0.73411, 0.73417),
+                               chunk_size=2)
+    labels = sweeprunner.enumerate_labels(spec)
+    seen, fresh_packs = set(), 0
+    for i in range(0, len(labels), 2):
+        rows = {(lb.logic, lb.hbm, lb.net, lb.scale)
+                for lb in labels[i:i + 2]}
+        fresh_packs += bool(rows - seen)
+        seen |= rows
+    assert len(labels) // 2 > fresh_packs == 2 and len(seen) == 4
+
+    def run(out):
+        return SweepRunner(spec, out_dir=str(tmp_path / out),
+                           backend="pipeline", cache=None,
+                           superbatch=2).run()
+
+    def fresh_caches():
+        monkeypatch.setattr(sweeprunner, "_HW_CACHE", {})
+        monkeypatch.setattr(sweeppipeline, "_ROW_CACHE",
+                            collections.OrderedDict())
+
+    fresh_caches()
+    with monkeypatch.context() as m:
+        m.setattr(age, "generate_rows", lambda techs, budgets: [
+            age.generate(t, b) for t, b in zip(techs, budgets)])
+        eager = run("eager")
+
+    fresh_caches()
+    s0 = age.age_stats()
+    batched = run("batched")
+    s1 = age.age_stats()
+    again = run("again")
+    s2 = age.age_stats()
+    assert s1["calls"] - s0["calls"] == fresh_packs
+    assert s1["rows"] - s0["rows"] == len(seen)
+    assert s1["eager_rows"] == s0["eager_rows"]
+    assert s2 == s1
+    assert batched.n_points_evaluated == len(labels)
+    for got in (batched.records, again.records):
+        got, want = _by_key(got), _by_key(eager.records)
+        assert got.keys() == want.keys()
+        for k, w in want.items():
+            for f, wv in w.items():
+                if isinstance(wv, float) and np.isfinite(wv):
+                    assert abs(got[k][f] - wv) <= 1e-6 * abs(wv), (k, f)
+                else:
+                    assert got[k][f] == wv, (k, f)
 
 
 def test_cli_frontier_only_and_cache_summary(tmp_path, capsys):
