@@ -4,11 +4,15 @@ A copy of the scalar `record` paths of the program's train and
 serving-traffic scenarios (with the serving memory model they use): one
 design point's phase rows in, one result record out, in float64 on the
 host.  Nothing here is batched, folded or compiled.
+
+The serving memory model comes from the caller as a `MemoryModel`: this
+module's `weight_bytes`, `kv_cache_bytes` and `_kv_shard_degree`, or a
+configuration's own (see `bench.crossflow`).
 """
 
 from __future__ import annotations
 
-from typing import Dict, List, Mapping, Tuple
+from typing import Callable, Dict, List, Mapping, NamedTuple, Tuple
 
 import numpy as np
 
@@ -88,11 +92,19 @@ def _kv_shard_degree(cfg: ArchConfig, st: Strategy) -> int:
     return st.dp * st.lp * max(kp_shard, 1)
 
 
-def serving_bytes_per_device(cfg: ArchConfig, st: Strategy,
-                             cell) -> Tuple[float, float]:
-    w_dev = weight_bytes(cfg) / max(st.kp * st.lp, 1)
-    kv_dev = kv_cache_bytes(cfg, cell.seq_len, cell.global_batch) \
-        / _kv_shard_degree(cfg, st)
+class MemoryModel(NamedTuple):
+    """What a serving record's memory fields are computed from."""
+
+    weight_bytes: Callable[[ArchConfig], float]
+    kv_cache_bytes: Callable[[ArchConfig, int, int], float]
+    kv_shard_degree: Callable[[ArchConfig, Strategy], int]
+
+
+def serving_bytes_per_device(cfg: ArchConfig, st: Strategy, cell,
+                             memory: MemoryModel) -> Tuple[float, float]:
+    w_dev = memory.weight_bytes(cfg) / max(st.kp * st.lp, 1)
+    kv_dev = memory.kv_cache_bytes(cfg, cell.seq_len, cell.global_batch) \
+        / memory.kv_shard_degree(cfg, st)
     return w_dev, kv_dev
 
 
@@ -105,13 +117,14 @@ def train_record(labels: Mapping, rows: np.ndarray) -> Dict:
 def serving_traffic_record(labels: Mapping, rows: np.ndarray,
                            cfg: ArchConfig, st: Strategy,
                            dram_capacity: float, cells: Tuple[str, str],
-                           params: Mapping) -> Dict:
+                           params: Mapping, memory: MemoryModel) -> Dict:
     """``params``: the traffic scenario's flat parameter dict (defaults,
-    the configuration's scalars and the record's variant overrides)."""
+    the configuration's scalars and the record's variant overrides);
+    ``memory``: the serving memory model of the configuration."""
     tm, policy, slo = traffic.split_params(
         {**traffic.PARAM_DEFAULTS, **params})
     pc, dc = SHAPE_CELLS[cells[0]], SHAPE_CELLS[cells[1]]
-    w_dev, kv_dev = serving_bytes_per_device(cfg, st, dc)
+    w_dev, kv_dev = serving_bytes_per_device(cfg, st, dc, memory)
     w_f, kv_f = float(w_dev), float(kv_dev)
     knee = roofline.CAPACITY_PRESSURE_KNEE
     cap = max(float(dram_capacity), 1.0)

@@ -9,8 +9,9 @@ The program marks its stages with ``jax.profiler.TraceAnnotation``:
 - ``repro.pipeline.pack``: the producer packing one superbatch (with its
   compile-ahead submission), on the producer thread or, inline, on the
   dispatching one;
-- ``repro.age.generate``: AGE building one fresh hardware row, inside a
-  pack;
+- ``repro.age.generate``: AGE resolving a pack's fresh hardware rows in
+  one batched `age.generate_rows` call, inside that pack (none where the
+  pack brings no fresh row);
 - ``repro.pipeline.wait_pack``: the dispatching thread blocked on the
   producer's queue (threaded mode);
 - ``repro.pipeline.dispatch``: one superbatch handed to the device;

@@ -8,6 +8,10 @@ device.  It never sees the program's pipeline, bucketing, compile-ahead,
 prediction cache or device-resident fold, and it takes nothing the program
 made: the architecture comes from the benchmark's configuration file.
 
+The parts of the model that depend on the architecture come from the
+module a configuration names under ``"reference"``, where it names one
+(see `bench.crossflow`), and otherwise from the frozen defaults.
+
 `Reference.control_record` is the same reference traced once and evaluated with every
 floating-point operation carried out in a lower precision; it is the
 control that each limit has to fail.
@@ -16,6 +20,7 @@ control that each limit has to fail.
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import itertools
 import math
 from typing import Dict, Iterable, List, Mapping, Optional, Sequence, Tuple
@@ -61,12 +66,25 @@ class Label:
                      strategy=str(rec["strategy"]))
 
 
-def arch_config(config: Mapping) -> ArchConfig:
-    a = dict(config["arch"])
-    for k in ("attn_pattern", "block_pattern"):
-        if k in a:
-            a[k] = tuple(a[k])
-    return ArchConfig(**a)
+def arch_module(config: Mapping):
+    """The module a configuration names under ``"reference"``, or None."""
+    name = config.get("reference")
+    if name is None:
+        return None
+    path = f"bench.crossflow.{name}"
+    try:
+        return importlib.import_module(path)
+    except ModuleNotFoundError as e:
+        if e.name != path:
+            raise
+        raise ValueError(f"configuration {config.get('name')!r}: "
+                         f"\"reference\" names the module {name!r}, but "
+                         f"there is no bench/crossflow/{name}.py") from None
+
+
+def arch_config(config: Mapping, cls=ArchConfig) -> ArchConfig:
+    return cls(**{k: tuple(v) if isinstance(v, list) else v
+                  for k, v in config["arch"].items()})
 
 
 def budgets(scale: float) -> age.Budgets:
@@ -84,7 +102,19 @@ class Reference:
     """The reference for one configuration file and one of its grids."""
 
     def __init__(self, config: Mapping, grid: Mapping):
-        self.cfg = arch_config(config)
+        mod = arch_module(config)
+
+        def part(name, default):
+            return default if mod is None else getattr(mod, name, default)
+
+        self.cfg = arch_config(config, part("ArchConfig", ArchConfig))
+        self._build_graph = part("build_graph", lmgraph.build_graph)
+        self._strategies = part("candidate_strategies",
+                                records.candidate_strategies)
+        self.memory = records.MemoryModel(
+            part("weight_bytes", records.weight_bytes),
+            part("kv_cache_bytes", records.kv_cache_bytes),
+            part("kv_shard_degree", records._kv_shard_degree))
         self.arch = str(config["program_arch"])
         self.scenario = str(config["scenario"])
         self.cells = tuple(config["cells"])
@@ -118,8 +148,7 @@ class Reference:
         scales = list(scales)
         for cell_id in self.cell_ids():
             for mesh in self.grid["meshes"]:
-                for st in records.candidate_strategies(self.cfg, primary,
-                                                       tuple(mesh)):
+                for st in self._strategies(self.cfg, primary, tuple(mesh)):
                     for logic, hbm, net, scale in itertools.product(
                             self.grid["logic"], self.grid["hbm"],
                             self.grid["net"], scales):
@@ -141,8 +170,8 @@ class Reference:
     def _graph(self, cell: str):
         g = self._graphs.get(cell)
         if g is None:
-            g = self._graphs[cell] = lmgraph.build_graph(self.cfg,
-                                                         SHAPE_CELLS[cell])
+            g = self._graphs[cell] = self._build_graph(self.cfg,
+                                                       SHAPE_CELLS[cell])
         return g
 
     def phase_cells(self, lb: Label) -> Tuple[str, ...]:
@@ -181,7 +210,7 @@ class Reference:
                       **traffic.decode_variant(lb.cell)[1]}
             rec = records.serving_traffic_record(
                 labels, rows, self.cfg, st, float(hw.dram_capacity),
-                self.phase_cells(lb), params)
+                self.phase_cells(lb), params, self.memory)
         rec["key"] = lb.key()
         return rec
 
