@@ -46,6 +46,20 @@ class ArchConfig:
     # weights — the §Perf hillclimb winner (no cross-shard scatter)
     moe_impl: str = "scatter_ep"
     moe_groups: int = 0             # grouped_tp: groups (0 -> DP degree)
+    # group-limited routing (DeepSeek-V3 n_group / topk_group): the router
+    # keeps the best topk_expert_groups of n_expert_groups groups of
+    # experts, then the top experts_per_token of those (0 = plain top-k)
+    n_expert_groups: int = 0
+    topk_expert_groups: int = 0
+    # leading dense layers of an MoE stack (first_k_dense_replace): layers
+    # below this index run the dense d_ff FFN instead of the experts
+    n_dense_layers: int = 0
+    # multi-head latent attention (MLA); kv_lora_rank > 0 selects it -------
+    q_lora_rank: int = 0            # query down-projection width
+    kv_lora_rank: int = 0           # width of the cached latent
+    qk_nope_head_dim: int = 0       # per-head query/key width without RoPE
+    qk_rope_head_dim: int = 0       # decoupled RoPE key, shared by heads
+    v_head_dim: int = 0             # per-head value width
     # encoder-decoder ---------------------------------------------------------
     is_encoder_decoder: bool = False
     n_encoder_layers: int = 0
@@ -77,6 +91,15 @@ class ArchConfig:
     def is_moe(self) -> bool:
         return self.n_experts > 0
 
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    def layer_is_moe(self, layer: int) -> bool:
+        """Whether ``layer`` runs the experts (the leading
+        ``n_dense_layers`` of an MoE stack run the dense FFN)."""
+        return self.is_moe and layer >= self.n_dense_layers
+
     def block_kind(self, layer: int) -> str:
         return self.block_pattern[layer % len(self.block_pattern)]
 
@@ -91,7 +114,19 @@ class ArchConfig:
         return _params(self, active_only=True)
 
 
+def _mla_params(cfg: ArchConfig) -> int:
+    d, nh = cfg.d_model, cfg.n_heads
+    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    q = d * cfg.q_lora_rank + cfg.q_lora_rank * nh * qk
+    kv = (d * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+          + cfg.kv_lora_rank * nh * (cfg.qk_nope_head_dim + cfg.v_head_dim))
+    o = nh * cfg.v_head_dim * d
+    return q + kv + o
+
+
 def _attn_params(cfg: ArchConfig) -> int:
+    if cfg.is_mla:
+        return _mla_params(cfg)
     hd = cfg.resolved_head_dim
     q = cfg.d_model * cfg.n_heads * hd
     kv = 2 * cfg.d_model * cfg.n_kv_heads * hd
@@ -104,11 +139,12 @@ def _ffn_params(cfg: ArchConfig, d_ff: int) -> int:
     return mult * cfg.d_model * d_ff
 
 
-def _block_params(cfg: ArchConfig, kind: str, active_only: bool) -> int:
+def _block_params(cfg: ArchConfig, kind: str, active_only: bool,
+                  moe: bool) -> int:
     d = cfg.d_model
     if kind == "attn":
         p = _attn_params(cfg)
-        if cfg.is_moe:
+        if moe:
             e_act = cfg.experts_per_token if active_only else cfg.n_experts
             p += e_act * _ffn_params(cfg, cfg.moe_d_ff)
             p += cfg.n_shared_experts * _ffn_params(cfg, cfg.moe_d_ff)
@@ -139,7 +175,8 @@ def _params(cfg: ArchConfig, active_only: bool) -> int:
         total += cfg.vocab_size * cfg.d_model
     layers = list(range(cfg.n_layers))
     for i in layers:
-        total += _block_params(cfg, cfg.block_kind(i), active_only)
+        total += _block_params(cfg, cfg.block_kind(i), active_only,
+                               cfg.layer_is_moe(i))
     if cfg.is_encoder_decoder:
         for i in range(cfg.n_encoder_layers):
             total += _attn_params(cfg) + _ffn_params(cfg, cfg.d_ff)
@@ -192,6 +229,7 @@ _ALIASES = {
     "qwen3-moe-30b-a3b": "qwen3_moe_30b_a3b",
     "xlstm-125m": "xlstm_125m",
     "paper-lm": "paper_lm",
+    "deepseek-v3": "deepseek_v3",
 }
 
 
@@ -234,6 +272,14 @@ def reduced(cfg: ArchConfig) -> ArchConfig:
         experts_per_token=min(cfg.experts_per_token, 2),
         moe_d_ff=64 if cfg.moe_d_ff else 0,
         n_shared_experts=min(cfg.n_shared_experts, 1),
+        n_expert_groups=min(cfg.n_expert_groups, 4),
+        topk_expert_groups=min(cfg.topk_expert_groups, 2),
+        n_dense_layers=min(cfg.n_dense_layers, 1),
+        q_lora_rank=min(cfg.q_lora_rank, 48),
+        kv_lora_rank=min(cfg.kv_lora_rank, 32),
+        qk_nope_head_dim=min(cfg.qk_nope_head_dim, 16),
+        qk_rope_head_dim=min(cfg.qk_rope_head_dim, 8),
+        v_head_dim=min(cfg.v_head_dim, 16),
         n_encoder_layers=min(cfg.n_encoder_layers, 2),
         decoder_len=16,
         n_patch_tokens=min(cfg.n_patch_tokens, 8),

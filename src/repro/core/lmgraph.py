@@ -6,7 +6,10 @@ GEMM nodes carry meta flags consumed by repro.core.transform:
   weight=True    participates in the DP gradient all-reduce;
   shard_k=False  contraction dim not shardable (stateful recurrences);
   moe=True       routed-expert GEMM (EP dispatch);
-  no_kp=True     not kernel-parallelizable at all.
+  no_kp=True     not kernel-parallelizable at all;
+  mla=True       a multi-head latent attention GEMM (counted by
+                 `graph_stats`, ignored by the transform);
+  dense=True     an FFN GEMM of a leading dense layer in an MoE stack.
 
 The per-layer subgraph is built once per distinct layer kind and replicated
 `count` times via `repeat` (homogeneous layers — the same observation the
@@ -16,12 +19,16 @@ paper uses for DP/KP replicas, §6.5, keeps graphs small at 88 layers).
 from __future__ import annotations
 
 import dataclasses
+import threading
 from typing import Dict, Optional, Tuple
 
 from repro.configs.base import ArchConfig, ShapeCell
 from repro.core.graph import ComputeGraph
 
 DTYPE_BYTES = 2                     # bf16 activations/weights in the model
+# group-limited routing per (token, expert) score: sigmoid, bias, the
+# group scores and two top-k selections, a few operations each
+ROUTER_SELECT_FLOPS = 10.0
 
 
 def _linear(g: ComputeGraph, name: str, tokens: int, d_in: int, d_out: int,
@@ -76,16 +83,82 @@ def _attention(g: ComputeGraph, name: str, cfg: ArchConfig, batch: int,
     return _linear(g, f"{name}.o", tokens, nh * hd, d, [last], train)
 
 
+def _mla(g: ComputeGraph, name: str, cfg: ArchConfig, batch: int,
+         q_len: int, kv_len: int, deps, train: bool) -> str:
+    """Multi-head latent attention (DeepSeek-V3).
+
+    Train and prefill run the unabsorbed form: queries through the
+    q_lora bottleneck, the latent (kv_lora_rank) plus the shared RoPE key
+    projected from every token, then up-projected to per-head keys and
+    values.  Decode runs the absorbed form over the latent cache: only the
+    new token is projected, q_nope is absorbed into the latent space per
+    head (W_UK), and the score and value GEMMs read the cached latent once
+    per sequence, as their (ctx, latent) operand; W_UV takes the result
+    back to v_head_dim per head.  The cache GEMMs put heads on N, which
+    the model axis splits, so each KP rank reads the whole latent.
+    """
+    d, nh = cfg.d_model, cfg.n_heads
+    nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    lat, qk = cfg.kv_lora_rank, nope + rope
+    tokens = batch * q_len
+    qa = _linear(g, f"{name}.q_a", tokens, d, cfg.q_lora_rank, deps, train,
+                 mla=True)
+    q = _linear(g, f"{name}.q_b", tokens, cfg.q_lora_rank, nh * qk, [qa],
+                train, mla=True)
+    if q_len == kv_len:
+        kv_tokens = batch * kv_len
+        kva = _linear(g, f"{name}.kv_a", kv_tokens, d, lat + rope, deps,
+                      train, mla=True)
+        kv = _linear(g, f"{name}.kv_b", kv_tokens, lat, nh * (nope + dv),
+                     [kva], train, mla=True)
+        ctx = max(int(kv_len * 0.5), 1)          # causal
+        head = dict(shard_m=False, shard_n=False, batch_dim="b", kp_b=True,
+                    gather_act=False, mla=True)
+        s = g.gemm(f"{name}.qk", b=batch * nh, m=q_len, n=ctx, k=qk,
+                   deps=[q, kv], **head)
+        sm = g.elementwise(f"{name}.softmax",
+                           n_elems=batch * nh * q_len * ctx,
+                           flops_per_elem=6.0, deps=[s.name])
+        last = g.gemm(f"{name}.av", b=batch * nh, m=q_len, n=dv, k=ctx,
+                      deps=[sm.name], **head).name
+        if train:
+            g.gemm(f"{name}.qk.bwd", b=batch * nh, m=q_len, n=ctx, k=qk,
+                   deps=[last], **head)
+            last = g.gemm(f"{name}.av.bwd", b=batch * nh, m=q_len, n=dv,
+                          k=ctx, deps=[f"{name}.qk.bwd"], **head).name
+    else:
+        per_head = dict(b=nh, shard_m=False, shard_n=False, kp_b=True,
+                        gather_act=False, weight=True, mla=True)
+        qn = g.gemm(f"{name}.q_absorb", m=tokens, n=lat, k=nope, deps=[q],
+                    **per_head)
+        kva = _linear(g, f"{name}.kv_a", tokens, d, lat + rope, deps, train,
+                      mla=True)
+        cache = dict(shard_m=False, batch_dim="b", gather_act=False,
+                     mla=True)
+        s = g.gemm(f"{name}.qk", b=batch, m=kv_len, n=q_len * nh,
+                   k=lat + rope, deps=[qn.name, kva], **cache)
+        sm = g.elementwise(f"{name}.softmax",
+                           n_elems=batch * nh * q_len * kv_len,
+                           flops_per_elem=6.0, deps=[s.name])
+        av = g.gemm(f"{name}.av", b=batch, m=lat, n=q_len * nh, k=kv_len,
+                    deps=[sm.name], **cache)
+        last = g.gemm(f"{name}.v_up", m=tokens, n=dv, k=lat,
+                      deps=[av.name], **per_head).name
+    return _linear(g, f"{name}.o", tokens, nh * dv, d, [last], train,
+                   mla=True)
+
+
 def _ffn(g: ComputeGraph, name: str, cfg: ArchConfig, tokens: int, deps,
-         train: bool, d_ff: Optional[int] = None, moe: bool = False) -> str:
+         train: bool, d_ff: Optional[int] = None, moe: bool = False,
+         **meta) -> str:
     d_ff = d_ff or cfg.d_ff
     mult = 2 if cfg.ffn_kind == "swiglu" else 1
     up = _linear(g, f"{name}.up", tokens, cfg.d_model, mult * d_ff, deps,
-                 train, moe=moe)
+                 train, moe=moe, **meta)
     act = g.elementwise(f"{name}.act", n_elems=tokens * d_ff,
                         flops_per_elem=4.0, deps=[up])
     return _linear(g, f"{name}.down", tokens, d_ff, cfg.d_model, [act.name],
-                   train, moe=moe)
+                   train, moe=moe, **meta)
 
 
 def _moe(g: ComputeGraph, name: str, cfg: ArchConfig, tokens: int, deps,
@@ -93,6 +166,10 @@ def _moe(g: ComputeGraph, name: str, cfg: ArchConfig, tokens: int, deps,
     # router
     r = _linear(g, f"{name}.router", tokens, cfg.d_model, cfg.n_experts,
                 deps, train)
+    if cfg.n_expert_groups:
+        r = g.elementwise(f"{name}.router.select",
+                          n_elems=tokens * cfg.n_experts,
+                          flops_per_elem=ROUTER_SELECT_FLOPS, deps=[r]).name
     # routed experts: per-token compute = top-k experts' FFW
     routed_tokens = tokens * cfg.experts_per_token
     last = _ffn(g, f"{name}.experts", cfg, routed_tokens, [r], train,
@@ -198,8 +275,7 @@ def build_graph(cfg: ArchConfig, cell: ShapeCell,
                     [last], train)
         g.elementwise("ce", n_elems=tokens * cfg.vocab_size,
                       flops_per_elem=4.0, deps=[h], dtype_bytes=4)
-        g.validate()
-        return g
+        return _built(g, {})
 
     last = g.gather("embed", rows=tokens, width=cfg.d_model).name
     if cfg.is_encoder_decoder and cell.kind == "prefill":
@@ -214,8 +290,8 @@ def build_graph(cfg: ArchConfig, cell: ShapeCell,
                       2 * cfg.n_kv_heads * cfg.resolved_head_dim, [e],
                       False)
         g.nodes[kvp].meta["repeat"] = cfg.n_layers
-        g.validate()
-        return g
+        return _built(g, {"enc": cfg.n_encoder_layers,
+                          "cross.kv": cfg.n_layers})
     if cfg.is_encoder_decoder:
         # encoder over seq_len frames; decoder over decoder_len tokens
         enc_tokens = (cell.seq_len * cell.global_batch
@@ -243,25 +319,40 @@ def build_graph(cfg: ArchConfig, cell: ShapeCell,
             g.nodes[name].meta["repeat"] = cfg.n_layers
         _linear(g, "lm_head", dec_tokens, cfg.d_model, cfg.vocab_size, [dec],
                 train)
-        g.validate()
-        return g
+        groups = {"dec": cfg.n_layers}
+        if enc_tokens:
+            groups["enc"] = cfg.n_encoder_layers
+        return _built(g, groups)
 
-    # decoder-only families: build each distinct (block kind, attn kind) once
-    kinds: Dict[Tuple[str, str], int] = {}
+    # decoder-only families: build each distinct (block kind, attn kind,
+    # FFN kind) once; the FFN kind names a group only where leading dense
+    # layers precede the experts
+    kinds: Dict[Tuple[str, str, str], int] = {}
     for i in range(cfg.n_layers):
         bk = cfg.block_kind(i)
         ak = cfg.attn_kind(i) if bk == "attn" else "-"
-        kinds[(bk, ak)] = kinds.get((bk, ak), 0) + 1
-    for (bk, ak), count in kinds.items():
+        fk = "moe" if cfg.layer_is_moe(i) else "dense"
+        kinds[(bk, ak, fk)] = kinds.get((bk, ak, fk), 0) + 1
+    dense_meta = {"dense": True} if cfg.n_dense_layers else {}
+    groups: Dict[str, int] = {}
+    for (bk, ak, fk), count in kinds.items():
         nm = f"{bk}.{ak}" if ak != "-" else bk
+        if cfg.n_dense_layers:
+            nm = f"{nm}.{fk}"
+        groups[nm] = count
         before = set(g.nodes)
         if bk == "attn":
-            a = _attention(g, f"{nm}.attn", cfg, batch, q_len, kv_len,
-                           [last], train, local=(ak == "local"))
-            if cfg.is_moe:
+            if cfg.is_mla:
+                a = _mla(g, f"{nm}.attn", cfg, batch, q_len, kv_len, [last],
+                         train)
+            else:
+                a = _attention(g, f"{nm}.attn", cfg, batch, q_len, kv_len,
+                               [last], train, local=(ak == "local"))
+            if fk == "moe":
                 e = _moe(g, f"{nm}.moe", cfg, tokens, [a], train)
             else:
-                e = _ffn(g, f"{nm}.ffn", cfg, tokens, [a], train)
+                e = _ffn(g, f"{nm}.ffn", cfg, tokens, [a], train,
+                         **dense_meta)
         elif bk == "rglru":
             r = _rglru(g, f"{nm}.rec", cfg, tokens, [last], train)
             e = _ffn(g, f"{nm}.ffn", cfg, tokens, [r], train)
@@ -277,8 +368,39 @@ def build_graph(cfg: ArchConfig, cell: ShapeCell,
     if train or cell.kind == "prefill":
         g.elementwise("ce", n_elems=tokens * cfg.vocab_size,
                       flops_per_elem=4.0, deps=[h])
+    return _built(g, groups)
+
+
+# what `build_graph` built, per graph name ("<arch>|<cell>")
+_STATS: Dict[str, object] = {"built": 0, "graphs": {}}
+_STATS_LOCK = threading.Lock()
+GEMM_TAGS = ("mla", "moe", "dense")
+
+
+def _built(g: ComputeGraph, groups: Dict[str, int]) -> ComputeGraph:
+    """Validate ``g`` and count it in `graph_stats`; ``groups`` maps each
+    layer group built once to the layers it stands for."""
     g.validate()
+    gemms = dict.fromkeys(GEMM_TAGS + ("other",), 0)
+    for n in g.nodes.values():
+        if n.kind == "gemm":
+            gemms[next((t for t in GEMM_TAGS if n.meta.get(t)), "other")] += 1
+    with _STATS_LOCK:
+        _STATS["built"] += 1
+        _STATS["graphs"][g.name] = {"gemm": gemms, "groups": len(groups),
+                                    "repeat": sum(groups.values())}
     return g
+
+
+def graph_stats() -> Dict[str, object]:
+    """What `build_graph` has built in this process: ``built`` graphs, and
+    per graph name (``<arch>|<cell>``) its GEMM nodes by tag (``mla``,
+    ``moe``, ``dense``, ``other``), its layer ``groups`` and the ``repeat``
+    they sum to (the layers they stand for)."""
+    with _STATS_LOCK:
+        return {"built": _STATS["built"],
+                "graphs": {k: dict(v, gemm=dict(v["gemm"]))
+                           for k, v in _STATS["graphs"].items()}}
 
 
 def expand_repeats(g: ComputeGraph) -> float:

@@ -30,6 +30,7 @@ import itertools
 import threading
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.configs.base import ArchConfig, SHAPE_CELLS, get_config
@@ -104,7 +105,8 @@ def workload_graph(arch: str, cell_name: str) -> ComputeGraph:
     with _GRAPH_LOCK:
         g = _GRAPH_CACHE.get(key)
     if g is None:
-        g = lmgraph.build_graph(get_config(arch), SHAPE_CELLS[cell_name])
+        with jax.profiler.TraceAnnotation("repro.lmgraph.build"):
+            g = lmgraph.build_graph(get_config(arch), SHAPE_CELLS[cell_name])
         with _GRAPH_LOCK:
             g = _GRAPH_CACHE.setdefault(key, g)
     return g
@@ -125,7 +127,9 @@ def kv_cache_bytes(cfg: ArchConfig, kv_len: int, batch: int,
     """Total KV-cache (+ recurrent-state) bytes for `batch` live sequences.
 
     Attention layers hold K+V per token: global layers over the full
-    context, local layers over min(context, window).  Recurrent blocks
+    context, local layers over min(context, window); latent-attention
+    (MLA) layers hold one latent plus one shared RoPE key per token
+    instead, (kv_lora_rank + qk_rope_head_dim) values.  Recurrent blocks
     (RG-LRU, m/sLSTM) hold O(1)-per-sequence state instead — this is
     exactly why hybrid archs win the long-context serving sweeps.
     """
@@ -145,7 +149,11 @@ def kv_cache_bytes(cfg: ArchConfig, kv_len: int, batch: int,
             ctx = kv_len
             if cfg.attn_kind(i) == "local":
                 ctx = min(kv_len, cfg.local_window)
-            per_seq += 2.0 * cfg.n_kv_heads * hd * ctx * dtype_bytes
+            if cfg.is_mla:
+                per_seq += float(cfg.kv_lora_rank + cfg.qk_rope_head_dim) \
+                    * ctx * dtype_bytes
+            else:
+                per_seq += 2.0 * cfg.n_kv_heads * hd * ctx * dtype_bytes
         elif bk == "rglru":
             w = cfg.lru_width or cfg.d_model
             per_seq += (w + cfg.conv1d_width * w) * 4  # f32 carry state
@@ -157,7 +165,11 @@ def kv_cache_bytes(cfg: ArchConfig, kv_len: int, batch: int,
 def _kv_shard_degree(cfg: ArchConfig, st: Strategy) -> int:
     """How many ways the KV cache is split: DP/LP always shard batch and
     layers; the model axis shards KV heads only up to n_kv_heads (GQA
-    floor) unless sequence parallelism shards the context dim instead."""
+    floor) unless sequence parallelism shards the context dim instead.
+    An MLA latent is read whole by every head, so the model axis splits
+    it only under sequence parallelism."""
+    if cfg.is_mla:
+        return st.dp * st.lp * (st.kp if st.sp > 1 else 1)
     kp_shard = min(st.kp, max(cfg.n_kv_heads, 1))
     if st.sp > 1:
         kp_shard = st.kp

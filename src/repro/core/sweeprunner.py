@@ -637,33 +637,37 @@ class SweepRunner:
         to results.jsonl (or kept in memory without an out_dir)."""
         t0 = time.perf_counter()
         stats0 = self._stat_snapshot()
-        labels = enumerate_labels(self.spec)
-        chunks = make_chunks(labels, self.spec.chunk_size)
-        done: Dict[int, str] = {}
-        journal: Optional[sweepexec.ChunkJournal] = None
-        memory_rows: List[Dict] = []
+        # the prologue before the first pack: the design points (meshes x
+        # strategies x hardware axes), their chunks and the output files
+        with jax.profiler.TraceAnnotation("repro.runner.prepare"):
+            labels = enumerate_labels(self.spec)
+            chunks = make_chunks(labels, self.spec.chunk_size)
+            done: Dict[int, str] = {}
+            journal: Optional[sweepexec.ChunkJournal] = None
+            memory_rows: List[Dict] = []
 
-        if self.out_dir is not None:
-            os.makedirs(self.out_dir, exist_ok=True)
-            spec_path, res_path, ckpt_path = self._paths()
-            if resume:
-                done = self._load_done(spec_path, ckpt_path, chunks)
-                self._compact_results(res_path, done)
-            elif os.path.exists(ckpt_path):
-                # never silently destroy a previous sweep's checkpoints: a
-                # forgotten --resume must not cost hours of finished chunks
-                raise FileExistsError(
-                    f"{self.out_dir} already holds a checkpointed sweep; "
-                    f"pass resume=True (CLI: --resume) to continue it, or "
-                    f"point --out at a fresh directory")
-            self._write_spec(spec_path)
-            journal = self._journal().open()
-        elif resume:
-            raise ValueError("resume=True requires an out_dir")
+            if self.out_dir is not None:
+                os.makedirs(self.out_dir, exist_ok=True)
+                spec_path, res_path, ckpt_path = self._paths()
+                if resume:
+                    done = self._load_done(spec_path, ckpt_path, chunks)
+                    self._compact_results(res_path, done)
+                elif os.path.exists(ckpt_path):
+                    # never silently destroy a previous sweep's checkpoints:
+                    # a forgotten --resume must not cost hours of finished
+                    # chunks
+                    raise FileExistsError(
+                        f"{self.out_dir} already holds a checkpointed sweep; "
+                        f"pass resume=True (CLI: --resume) to continue it, or "
+                        f"point --out at a fresh directory")
+                self._write_spec(spec_path)
+                journal = self._journal().open()
+            elif resume:
+                raise ValueError("resume=True requires an out_dir")
 
-        pending = [c for c in chunks if c.index not in done]
-        if max_chunks is not None:
-            pending = pending[:max_chunks]
+            pending = [c for c in chunks if c.index not in done]
+            if max_chunks is not None:
+                pending = pending[:max_chunks]
 
         n_eval_points = 0
 
@@ -745,44 +749,44 @@ class SweepRunner:
         from repro.core import sweeppipeline
         t0 = time.perf_counter()
         stats0 = self._stat_snapshot()
-        labels = enumerate_labels(self.spec)
-        chunks = make_chunks(labels, self.spec.chunk_size)
-        state0 = None
-        done: Dict[int, str] = {}
-        state_path = None
-        if self.out_dir is not None:
-            # validate the destination BEFORE evaluating anything: a
-            # guard that fires after the sweep would discard hours of
-            # frontier compute
-            spec_path, _, ckpt_path = self._paths()
-            state_path = self._frontier_state_path()
-            if resume:
-                state0, done = self._load_frontier_state(
-                    spec_path, state_path, ckpt_path, chunks, capacity)
-            else:
-                os.makedirs(self.out_dir, exist_ok=True)
-                if os.path.exists(ckpt_path):
-                    raise FileExistsError(
-                        f"{self.out_dir} already holds a checkpointed "
-                        f"sweep; frontier-only output would shadow it — "
-                        f"point --out at a fresh directory")
-                if os.path.exists(state_path):
-                    raise FileExistsError(
-                        f"{self.out_dir} already holds a frontier-state "
-                        f"checkpoint; pass resume=True (CLI: --resume) to "
-                        f"continue it, or point --out at a fresh "
-                        f"directory")
-            self._write_spec(spec_path)
-        elif resume:
-            raise ValueError("resume=True requires an out_dir")
-        pending = [c for c in chunks if c.index not in done]
-        if max_chunks is not None:
-            pending = pending[:max_chunks]
-        ex = sweeppipeline.PipelineExecutor(self.spec, cache=self.cache,
-                                            superbatch=self.superbatch
-                                            or sweeppipeline.SUPERBATCH,
-                                            compile_ahead=self.compile_ahead,
-                                            bucketing=self.bucketing)
+        with jax.profiler.TraceAnnotation("repro.runner.prepare"):
+            labels = enumerate_labels(self.spec)
+            chunks = make_chunks(labels, self.spec.chunk_size)
+            state0 = None
+            done: Dict[int, str] = {}
+            state_path = None
+            if self.out_dir is not None:
+                # validate the destination BEFORE evaluating anything: a
+                # guard that fires after the sweep would discard hours of
+                # frontier compute
+                spec_path, _, ckpt_path = self._paths()
+                state_path = self._frontier_state_path()
+                if resume:
+                    state0, done = self._load_frontier_state(
+                        spec_path, state_path, ckpt_path, chunks, capacity)
+                else:
+                    os.makedirs(self.out_dir, exist_ok=True)
+                    if os.path.exists(ckpt_path):
+                        raise FileExistsError(
+                            f"{self.out_dir} already holds a checkpointed "
+                            f"sweep; frontier-only output would shadow it — "
+                            f"point --out at a fresh directory")
+                    if os.path.exists(state_path):
+                        raise FileExistsError(
+                            f"{self.out_dir} already holds a frontier-state "
+                            f"checkpoint; pass resume=True (CLI: --resume) to "
+                            f"continue it, or point --out at a fresh "
+                            f"directory")
+                self._write_spec(spec_path)
+            elif resume:
+                raise ValueError("resume=True requires an out_dir")
+            pending = [c for c in chunks if c.index not in done]
+            if max_chunks is not None:
+                pending = pending[:max_chunks]
+            ex = sweeppipeline.PipelineExecutor(
+                self.spec, cache=self.cache,
+                superbatch=self.superbatch or sweeppipeline.SUPERBATCH,
+                compile_ahead=self.compile_ahead, bucketing=self.bucketing)
         on_commit = None
         if state_path is not None:
             committed = dict(done)

@@ -36,6 +36,13 @@ class Model:
 
 
 def build_model(cfg: ArchConfig) -> Model:
+    if cfg.is_mla:
+        # the layer library has no latent attention: building GQA under
+        # an MLA model's name would run a different model
+        raise NotImplementedError(
+            f"{cfg.name}: multi-head latent attention (kv_lora_rank="
+            f"{cfg.kv_lora_rank}) has no runtime model; it is costed on "
+            f"the pathfinding path only (repro.core.lmgraph)")
     if cfg.family == "lstm":
         return Model(
             cfg=cfg, defs=lstm.lstm_defs(cfg),
